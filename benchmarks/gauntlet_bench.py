@@ -39,13 +39,13 @@ largest shared peer count is at least X times below the no-mesh leg's
 Peers are simulated by publishing format-valid random payloads through
 ONE shared jitted fabricator (noise + compress fused: a single dispatch
 per peer per round, which is what makes 1024-peer rounds practical to
-generate). ``--scheme`` selects the gradient scheme. ``--compile-cache
-DIR`` turns on the persistent XLA compilation cache so a second run
-compiles warm (see repro.launch.compile_cache).
+generate). ``--scheme`` selects the gradient scheme. ``--compile-cache``
+turns on the persistent XLA compilation cache so a second run compiles
+warm (see repro.launch.compile_cache).
 
 Run:  PYTHONPATH=src python benchmarks/gauntlet_bench.py [--rounds N]
           [--peers 8 16 32 64] [--mesh-devices 0 4] [--eval-chunk 8]
-          [--scheme demo] [--compile-cache DIR]
+          [--scheme demo] [--compile-cache]
           [--out BENCH_gauntlet.json] [--check BENCH_gauntlet.json]
 """
 from __future__ import annotations
@@ -313,8 +313,9 @@ def main():
                          "(0 = full vmap)")
     ap.add_argument("--scheme", default="demo",
                     help="gradient scheme (repro.schemes registry name)")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache directory "
+    ap.add_argument("--compile-cache", action="store_true",
+                    help="persistent XLA compilation cache in "
+                         "$JAX_COMPILATION_CACHE_DIR, else .jax_cache/ "
                          "(second run compiles warm)")
     ap.add_argument("--out", default="BENCH_gauntlet.json",
                     help="schema-stable trajectory artifact "
@@ -337,7 +338,7 @@ def main():
                          "multi-device host)")
     args = ap.parse_args()
     if args.compile_cache:
-        enable_compile_cache(args.compile_cache)
+        enable_compile_cache()
     legs = []
     for md in args.mesh_devices:
         peer_list = (args.mesh_peers if md and args.mesh_peers is not None

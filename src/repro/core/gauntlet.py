@@ -593,10 +593,10 @@ class Validator:
             return (sk, fingerprint.cosine_matrix(sk_loc, sk),
                     fingerprint.cosine_matrix(sk_loc, ref))
 
-        from repro.sharding import compat_shard_map
-        return compat_shard_map(
-            shard, self.mesh, (P(ax), P()),
-            (P(), P(ax), P(ax)), {ax})(stacked, ref)
+        return jax.shard_map(
+            shard, mesh=self.mesh, in_specs=(P(ax), P()),
+            out_specs=(P(), P(ax), P(ax)), axis_names={ax},
+            check_vma=False)(stacked, ref)
 
     def _sketch_impl(self, stacked):
         """Sketches alone (replayed payloads get compared host-side)."""

@@ -246,6 +246,7 @@ class Roofline:
     mesh: str
     variant: str
     chips: int
+    device_kind: str             # keys launch.mesh.PEAKS
     hlo_gflops: float            # per chip
     hlo_gbytes: float            # per chip
     collective_gbytes: float     # per chip
@@ -257,9 +258,10 @@ class Roofline:
     collective_s: float = 0.0
 
     def finalize(self):
-        self.compute_s = (self.hlo_gflops * 1e9 / mesh_mod.PEAK_FLOPS_BF16)
-        self.memory_s = (self.hlo_gbytes * 1e9 / mesh_mod.HBM_BW)
-        self.collective_s = (self.collective_gbytes * 1e9 / mesh_mod.ICI_BW)
+        peaks = mesh_mod.device_peaks(self.device_kind)
+        self.compute_s = self.hlo_gflops * 1e9 / peaks.flops_bf16
+        self.memory_s = self.hlo_gbytes * 1e9 / peaks.hbm_bw
+        self.collective_s = self.collective_gbytes * 1e9 / peaks.ici_bw
         return self
 
     @property
@@ -286,7 +288,8 @@ class Roofline:
 
 
 def analyze(compiled, lowered, *, arch: str, shape_name: str, mesh_name: str,
-            variant: str, chips: int, model_flops: float) -> Roofline:
+            variant: str, chips: int, model_flops: float,
+            device_kind: str) -> Roofline:
     ca = compiled.cost_analysis() or {}
     try:
         hlo = compiled.as_text()
@@ -305,7 +308,7 @@ def analyze(compiled, lowered, *, arch: str, shape_name: str, mesh_name: str,
                  + getattr(mem, "argument_size_in_bytes", 0))
     r = Roofline(
         arch=arch, shape=shape_name, mesh=mesh_name, variant=variant,
-        chips=chips,
+        chips=chips, device_kind=device_kind,
         hlo_gflops=flops / 1e9,
         hlo_gbytes=byts / 1e9,
         collective_gbytes=coll_total / 1e9,

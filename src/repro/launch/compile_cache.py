@@ -1,56 +1,40 @@
-"""Persistent XLA compilation cache for the validator's round programs.
+"""Persistent XLA compilation cache.
 
-The Gauntlet's cold-start cost is compilation, not math: BENCH_gauntlet
-shows 20-32 s of ``compile_round_ms`` against ~3 s steady rounds. The
-programs themselves are stable across runs (sticky pow2 buckets pin the
-shapes), so a persistent on-disk cache makes round 1 of run 2 warm — the
-second process pays tracing/lowering only and loads the executables.
+A cold start is dominated by compilation, not math: the Gauntlet's round
+programs and the peer's train step are stable across runs (sticky pow2
+buckets pin the shapes), so an on-disk cache lets a second process load
+the executables instead of compiling them.
 
-``enable_compile_cache`` is safe to call unconditionally:
+Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and this
+module sets no directory. Otherwise the cache lives at a fixed path
+inside the checkout (``.jax_cache/``): a directory that moves between
+runs never hits.
 
-* With an explicit ``path`` it turns the cache on at that directory.
-* With ``path=None`` it consults the ``REPRO_COMPILE_CACHE`` env var and
-  is a NO-OP when that is unset — callers on the hot import path (the
-  sim engine, the bench) can invoke it without changing default
-  behaviour or touching jax config for users who didn't opt in.
-
-The thresholds are floored to zero/-1 so even the tiny CI-sized round
+The thresholds are floored to zero/-1 so even the small CPU-sized round
 programs (sub-second compiles) are cached; the default jax thresholds
-would skip exactly the programs the bench measures.
+would skip exactly the programs ``benchmarks/compile_cache_check.py``
+measures.
 """
 from __future__ import annotations
 
 import os
-from typing import Optional
 
-ENV_VAR = "REPRO_COMPILE_CACHE"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
-_enabled_at: Optional[str] = None
 
-
-def enable_compile_cache(path: Optional[str] = None,
-                         min_compile_secs: float = 0.0) -> Optional[str]:
-    """Point jax's persistent compilation cache at ``path`` (or at
-    ``$REPRO_COMPILE_CACHE``; no-op if both are unset). Returns the
-    directory in effect, or None when disabled. Idempotent."""
-    global _enabled_at
-    if path is None:
-        path = os.environ.get(ENV_VAR) or None
-    if path is None:
-        return _enabled_at
-    path = os.path.abspath(path)
-    if _enabled_at == path:
-        return path
-
+def enable_compile_cache() -> str:
+    """Turn jax's persistent compilation cache on and return its
+    directory (``$JAX_COMPILATION_CACHE_DIR``, else ``DEFAULT_DIR``).
+    Idempotent; call it before the first compile to cache that too."""
     import jax
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      float(min_compile_secs))
+    path = os.environ.get(ENV_VAR)
+    if not path:
+        path = DEFAULT_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    try:
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-    except AttributeError:
-        pass  # knob landed after jax 0.4.3x; the main cache still works
-    _enabled_at = path
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
     return path

@@ -24,7 +24,7 @@ from repro.configs.base import TrainConfig
 from repro.configs.registry import ASSIGNED_ARCHS, get_config, get_shape
 from repro.configs.shapes import SHAPES
 from repro.launch import analysis
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.launch.steps import make_step
 
 # combos that are skipped by design (DESIGN.md §5)
@@ -74,7 +74,8 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, variant: str,
     roof = analysis.analyze(
         compiled, lowered, arch=arch, shape_name=shape_name,
         mesh_name=mesh_name, variant=variant, chips=chips,
-        model_flops=analysis.model_flops(cfg, shape))
+        model_flops=analysis.model_flops(cfg, shape),
+        device_kind=PRODUCTION_DEVICE_KIND)
     rec = roof.to_dict()
     rec.update(status="ok", lower_s=round(t_lower, 1),
                compile_s=round(t_compile, 1),

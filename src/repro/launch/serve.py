@@ -15,8 +15,7 @@ import jax.numpy as jnp
 from repro.configs.base import InputShape
 from repro.configs.registry import (ASSIGNED_ARCHS, get_config,
                                     reduced_config)
-from repro.launch.mesh import (make_host_mesh, make_production_mesh,
-                               mesh_context)
+from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import make_serve_step, use_scan
 from repro.models import model as M
 
@@ -57,7 +56,7 @@ def main(argv=None):
         cache = M.group_cache(cache, cfg)
     tok = jax.random.randint(key, (args.batch, 1), 0, cfg.vocab_size)
     outs = []
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.time()
         for _ in range(args.tokens):
             logits, cache = compiled(params, cache, tok)

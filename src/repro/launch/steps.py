@@ -127,8 +127,7 @@ class StepPlan:
         if self.donate:
             kw["donate_argnums"] = self.donate
         from repro.hints import axis_hints
-        from repro.launch.mesh import mesh_context
-        with mesh_context(mesh), axis_hints(
+        with jax.set_mesh(mesh), axis_hints(
                 **(self.hints or {"head": "model"})):
             return jax.jit(self.fn, in_shardings=in_shardings,
                            **kw).lower(*self.args)
@@ -222,11 +221,12 @@ def _peer_round_plan(cfg: ModelConfig, mesh, *, name: str,
     bspecs = sh.batch_specs(cfg, batch_sds, peers, mesh)
 
     def step(params, state, batch, step_idx):
-        return sh.compat_shard_map(
-            per_peer, mesh,
-            (manual_p, manual_s, manual_b, P()),
-            (manual_p, manual_s, P()),
-            set(peers))(params, state, batch, step_idx)
+        return jax.shard_map(
+            per_peer, mesh=mesh,
+            in_specs=(manual_p, manual_s, manual_b, P()),
+            out_specs=(manual_p, manual_s, P()),
+            axis_names=set(peers), check_vma=False)(
+                params, state, batch, step_idx)
 
     return StepPlan(
         name=name, fn=step,

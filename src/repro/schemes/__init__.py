@@ -44,10 +44,11 @@ import jax.numpy as jnp
 
 def tree_signature(params) -> tuple:
     """Hashable (structure, shapes, dtypes) fingerprint of a pytree —
-    the jit-cache key ingredient for shape-polymorphic shared programs."""
+    the jit-cache key ingredient for shape-polymorphic shared programs.
+    Leaves may be arrays or ``jax.ShapeDtypeStruct`` stand-ins."""
     leaves, treedef = jax.tree.flatten(params)
     return (treedef,
-            tuple((tuple(l.shape), str(jnp.asarray(l).dtype))
+            tuple((tuple(l.shape), str(jnp.dtype(l.dtype)))
                   for l in leaves))
 
 

@@ -352,28 +352,20 @@ def grouped_cache_specs(cfg: ModelConfig, gcache: DecodeCache, mesh,
 # *scored-peer* dimension of its round entry points, not the batch.
 PEER_AXIS = "peers"
 
+# How far a multi-device peer mesh may move a LossScore from the unsharded
+# round. A LossScore is L(θ) − L(θ − βΔ) in float32; splitting the rows
+# over devices changes XLA's reduction order inside each loss, which moves
+# it by about an ulp (9.5e-7 below 16), so the difference can move by
+# two. A relative bound means nothing on such a near-cancelling
+# difference. Weights, audit flags and aggregated params stay exact.
+MESH_SCORE_ATOL = 2e-6
+
 
 def peer_mesh_size(mesh) -> int:
     """Device count along the validator peer axis (1 for mesh=None)."""
     if mesh is None:
         return 1
     return int(dict(mesh.shape).get(PEER_AXIS, 1))
-
-
-def compat_shard_map(fn, mesh, in_specs, out_specs, axis_names):
-    """``shard_map`` across jax versions, same semantics either way:
-    manual over ``axis_names``, auto over the rest, no replication/VMA
-    check. Newer jax exposes it at top level (``axis_names``/
-    ``check_vma``); older releases ship ``jax.experimental.shard_map``
-    where the manual set is 'every mesh axis minus ``auto``'."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=set(axis_names), check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False,
-               auto=frozenset(mesh.axis_names) - set(axis_names))
 
 
 def shard_map_rows(mesh, fn, row_args, axis: str = PEER_AXIS):
@@ -393,8 +385,9 @@ def shard_map_rows(mesh, fn, row_args, axis: str = PEER_AXIS):
     def wrapped(*args):
         in_specs = tuple(P(axis) if i in row_args else P()
                          for i in range(len(args)))
-        return compat_shard_map(fn, mesh, in_specs, P(axis),
-                                {axis})(*args)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=P(axis), axis_names={axis},
+                             check_vma=False)(*args)
 
     return wrapped
 

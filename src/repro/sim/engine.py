@@ -499,9 +499,9 @@ class SimEngine:
         many local devices (``launch.mesh.make_peer_mesh``): the round
         entry points shard their peer axis and an N-device validator
         scores ~N× peers per wall-clock round. Results are bit-identical
-        to ``mesh_devices=0`` on one device. Set ``REPRO_COMPILE_CACHE``
-        to a directory to also persist compiled round programs across
-        runs (warm start on run 2).
+        to ``mesh_devices=0`` on one device. Compiled round programs
+        persist across runs in the compilation cache
+        (``launch.compile_cache``), so run 2 starts warm.
 
         ``obs`` (a :class:`repro.obs.FlightRecorder`) attaches the
         flight recorder to every validator and the engine: round/stage
@@ -515,7 +515,7 @@ class SimEngine:
         from repro.models import model as M
         from repro.schemes import make_scheme
 
-        enable_compile_cache()          # no-op unless the env var is set
+        enable_compile_cache()
         mesh = make_peer_mesh(mesh_devices) if mesh_devices else None
         cfg = cfg or tiny_config()
         n_specs = len(scenario.peers)

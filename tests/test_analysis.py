@@ -12,12 +12,6 @@ from repro.launch import analysis
 from repro.launch.analysis import sim_telemetry_summary
 
 
-def _cost_analysis(compiled):
-    # older jaxlib returns a one-element list of dicts, newer a dict
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
-
-
 def test_scan_flops_weighted_by_trip_count():
     def f(x):
         def body(c, _):
@@ -32,7 +26,7 @@ def test_scan_flops_weighted_by_trip_count():
     assert abs(r["flops"] - expected) / expected < 0.05
     # cost_analysis undercounts (counts the body once) — that's the bug
     # this parser exists to fix
-    assert _cost_analysis(c)["flops"] < 0.5 * expected
+    assert c.cost_analysis()["flops"] < 0.5 * expected
 
 
 def test_matches_cost_analysis_on_loop_free_program():
@@ -42,7 +36,7 @@ def test_matches_cost_analysis_on_loop_free_program():
     sds = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     c = jax.jit(g).lower(sds, sds).compile()
     r = analysis.hlo_costs(c.as_text())
-    ca = _cost_analysis(c)
+    ca = c.cost_analysis()
     assert abs(r["flops"] - ca["flops"]) / ca["flops"] < 0.05
     assert abs(r["bytes"] - ca["bytes accessed"]) / ca["bytes accessed"] < 0.2
 

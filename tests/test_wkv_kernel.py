@@ -27,7 +27,8 @@ def _inputs(bh, t, n, key=0):
 ])
 def test_wkv_kernel_matches_oracle(bh, t, n, chunk):
     r, k, v, lw, u = _inputs(bh, t, n, key=bh + t)
-    o_k, s_k = ops.wkv_chunks(r, k, v, lw, u, chunk=chunk)
+    o_k, s_k = ops.wkv_chunks(r, k, v, lw, u, chunk=chunk,
+                              interpret=True)
     o_r, s_r = ref.wkv_chunks(r, k, v, lw, u, chunk=chunk)
     np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r),
                                rtol=1e-4, atol=1e-4)
@@ -38,8 +39,10 @@ def test_wkv_kernel_matches_oracle(bh, t, n, chunk):
 def test_wkv_kernel_seq_blocking_carries_state():
     """State must flow across seq-block grid steps (T split into 2)."""
     r, k, v, lw, u = _inputs(2, 256, 64, key=11)
-    o_full, s_full = ops.wkv_chunks(r, k, v, lw, u, chunk=64)
-    o_blk, s_blk = ops.wkv_chunks(r, k, v, lw, u, chunk=64, seq_block=128)
+    o_full, s_full = ops.wkv_chunks(r, k, v, lw, u, chunk=64,
+                                    interpret=True)
+    o_blk, s_blk = ops.wkv_chunks(r, k, v, lw, u, chunk=64, seq_block=128,
+                                  interpret=True)
     np.testing.assert_allclose(np.asarray(o_blk), np.asarray(o_full),
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(s_blk), np.asarray(s_full),
@@ -51,7 +54,8 @@ def test_wkv_kernel_decay_semantics():
     bh, t, n = 1, 128, 64
     r, k, v, lw, u = _inputs(bh, t, n, key=3)
     hard = jnp.full_like(lw, -8.0)   # MIN_LOG_W: ~e^-8 per step
-    o_k, s_k = ops.wkv_chunks(r, k, v, hard, u, chunk=64)
+    o_k, s_k = ops.wkv_chunks(r, k, v, hard, u, chunk=64,
+                              interpret=True)
     o_r, s_r = ref.wkv_chunks(r, k, v, hard, u, chunk=64)
     np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r),
                                rtol=1e-4, atol=1e-4)
@@ -77,7 +81,7 @@ def test_wkv_kernel_matches_model_time_mix_core():
     # kernel layout: (B*H, T, N)
     tohw = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, T, N)
     o_k, s_k = ops.wkv_chunks(tohw(r), tohw(k), tohw(v), tohw(lw),
-                              u[0], chunk=64)
+                              u[0], chunk=64, interpret=True)
     # accumulation order differs between the batched-einsum model path
     # and the per-head kernel loop: agreement to ~5e-3 absolute
     np.testing.assert_allclose(
