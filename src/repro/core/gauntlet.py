@@ -484,10 +484,13 @@ class Validator:
         """Wrap a jit impl so its Python body bumps ``trace_counts`` —
         the body only executes when XLA (re)traces, so the counter is
         the compile count for that entry point (the retrace-regression
-        test and the bench assert it stays flat across churn)."""
+        test and the bench assert it stays flat across churn). The
+        wrapper takes the entry point's name, so each one compiles to
+        its own ``jit_validator_<name>`` module in a profiler trace."""
         def wrapped(*args):
             self.trace_counts[name] += 1
             return fn(*args)
+        wrapped.__name__ = wrapped.__qualname__ = f"validator_{name}"
         return wrapped
 
     def _baselines_impl(self, chunk, params, uniq_a, uniq_r,

@@ -14,8 +14,11 @@ import functools
 import math
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.obs import trace
 
 
 @functools.lru_cache(maxsize=None)
@@ -106,13 +109,17 @@ def idct2(coeffs: jnp.ndarray) -> jnp.ndarray:
 
 def encode(x: jnp.ndarray, meta: ChunkMeta) -> jnp.ndarray:
     """Tensor -> flat per-chunk DCT coefficients (num_chunks, s*s)."""
-    c = dct2(to_chunks(x, meta))
-    # (R,s,C,s) -> (R,C,s,s) -> (RC, s*s)
-    return c.transpose(0, 2, 1, 3).reshape(meta.num_chunks, meta.s * meta.s)
+    with jax.named_scope(trace.SCOPE_ENCODE):
+        c = dct2(to_chunks(x, meta))
+        # (R,s,C,s) -> (R,C,s,s) -> (RC, s*s)
+        return c.transpose(0, 2, 1, 3).reshape(meta.num_chunks,
+                                               meta.s * meta.s)
 
 
 def decode(coeffs_flat: jnp.ndarray, meta: ChunkMeta) -> jnp.ndarray:
     """(num_chunks, s*s) coefficients -> tensor in original shape."""
     s = meta.s
-    c = coeffs_flat.reshape(meta.rows, meta.cols, s, s).transpose(0, 2, 1, 3)
-    return from_chunks(idct2(c), meta)
+    with jax.named_scope(trace.SCOPE_DECODE):
+        c = coeffs_flat.reshape(meta.rows, meta.cols, s, s).transpose(
+            0, 2, 1, 3)
+        return from_chunks(idct2(c), meta)

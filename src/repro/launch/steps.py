@@ -27,6 +27,7 @@ from repro.configs.base import InputShape, ModelConfig, TrainConfig
 from repro.demo import adamw, dct
 from repro.demo.schedules import warmup_cosine
 from repro.models import model as M
+from repro.obs import trace
 # the tuned production step is DeMo-specific by design: it IS the demo
 # scheme's codec lowered onto the mesh (all_gather of Payload trees).
 # Other schemes lower through make_scheme_train_step, which reuses the
@@ -145,8 +146,6 @@ def make_grad_fn(loss_of, microbatch: int):
             return x.reshape((microbatch, x.shape[0] // microbatch)
                              + x.shape[1:])
 
-        mbs = jax.tree.map(slice_mb, batch)
-
         def body(carry, mb):
             loss_acc, g_acc = carry
             l, g = jax.value_and_grad(loss_of)(params, mb)
@@ -154,12 +153,14 @@ def make_grad_fn(loss_of, microbatch: int):
                 lambda a, b: a + b.astype(jnp.float32), g_acc, g)
             return (loss_acc + l, g_acc), None
 
-        g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
-                          params)
-        (loss, grads), _ = jax.lax.scan(
-            body, (jnp.float32(0.0), g0), mbs)
-        inv = 1.0 / microbatch
-        return loss * inv, jax.tree.map(lambda g: g * inv, grads)
+        with jax.named_scope(trace.SCOPE_ACCUMULATE):
+            mbs = jax.tree.map(slice_mb, batch)
+            g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
+                              params)
+            (loss, grads), _ = jax.lax.scan(
+                body, (jnp.float32(0.0), g0), mbs)
+            inv = 1.0 / microbatch
+            return loss * inv, jax.tree.map(lambda g: g * inv, grads)
 
     return grad_of
 
@@ -275,16 +276,19 @@ def make_demo_train_step(cfg: ModelConfig, hp: TrainConfig, mesh,
         from repro import hints as _hints
 
         def leaf(e, g, m):
-            e32 = hp.demo_beta * e.astype(jnp.float32) + g.astype(jnp.float32)
-            # keep every params-sized compression stage sharded by chunk
-            # rows (the flatten/pad reshapes otherwise make GSPMD
-            # replicate the whole fp32 pipeline — §Perf pair B)
-            coeffs = _hints.constrain_chunks(dct.encode(e32, m))
+            with jax.named_scope(trace.SCOPE_ENCODE):
+                e32 = (hp.demo_beta * e.astype(jnp.float32)
+                       + g.astype(jnp.float32))
+                # keep every params-sized compression stage sharded by
+                # chunk rows (the flatten/pad reshapes otherwise make
+                # GSPMD replicate the whole fp32 pipeline — §Perf pair B)
+                coeffs = _hints.constrain_chunks(dct.encode(e32, m))
             payload = demo_opt.topk_compress(coeffs, hp.demo_topk)
-            dense = _hints.constrain_chunks(
-                demo_opt.topk_decompress(payload, m.s * m.s))
-            z = dct.decode(dense, m)
-            return payload, (e32 - z).astype(ef_dtype)
+            with jax.named_scope(trace.SCOPE_DECODE):
+                dense = _hints.constrain_chunks(
+                    demo_opt.topk_decompress(payload, m.s * m.s))
+                z = dct.decode(dense, m)
+                return payload, (e32 - z).astype(ef_dtype)
         flat_e, tdef = jax.tree.flatten(ef)
         outs = [leaf(e, g, m) for e, g, m in zip(
             flat_e, jax.tree.leaves(grads), jax.tree.leaves(metas))]

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.models import attention, layers, mla, moe, rwkv6, ssm
 from repro.configs.base import ModelConfig
+from repro.obs import trace
 
 # --------------------------------------------------------------- helpers
 
@@ -252,11 +253,12 @@ def _trunk_stacked(params, batch, cfg: ModelConfig, num_groups: int,
 def forward(params, batch, cfg: ModelConfig, num_groups: int = 1,
             remat: bool = False, scan_layers: bool = False):
     trunk = _trunk_stacked if scan_layers else _trunk
-    x, offset, _ = trunk(params, batch, cfg, num_groups, remat)
-    logits = _unembed(params, x, cfg)
-    if offset:
-        logits = logits[:, offset:]
-    return logits
+    with jax.named_scope(trace.SCOPE_MODEL):
+        x, offset, _ = trunk(params, batch, cfg, num_groups, remat)
+        logits = _unembed(params, x, cfg)
+        if offset:
+            logits = logits[:, offset:]
+        return logits
 
 
 def loss_fn(params, batch, cfg: ModelConfig, num_groups: int = 1,
@@ -264,20 +266,21 @@ def loss_fn(params, batch, cfg: ModelConfig, num_groups: int = 1,
             scan_layers: bool = False):
     """Next-token LM loss. Returns (loss, metrics)."""
     trunk = _trunk_stacked if scan_layers else _trunk
-    x, offset, aux = trunk(params, batch, cfg, num_groups, remat)
-    if offset:
-        x = x[:, offset:]
-    labels = batch["labels"]
-    mask = batch.get("loss_mask")
-    if ce_chunks > 1:
-        emb_w = (params["embed"]["w"] if cfg.tie_embeddings
-                 else params["lm_head"]["w"].T)
-        ce = layers.chunked_cross_entropy(x, emb_w.astype(x.dtype), labels,
-                                          mask, ce_chunks)
-    else:
-        logits = _unembed(params, x, cfg)
-        ce = layers.cross_entropy(logits, labels, mask)
-    return ce + aux, {"ce": ce, "aux": aux}
+    with jax.named_scope(trace.SCOPE_MODEL):
+        x, offset, aux = trunk(params, batch, cfg, num_groups, remat)
+        if offset:
+            x = x[:, offset:]
+        labels = batch["labels"]
+        mask = batch.get("loss_mask")
+        if ce_chunks > 1:
+            emb_w = (params["embed"]["w"] if cfg.tie_embeddings
+                     else params["lm_head"]["w"].T)
+            ce = layers.chunked_cross_entropy(x, emb_w.astype(x.dtype),
+                                              labels, mask, ce_chunks)
+        else:
+            logits = _unembed(params, x, cfg)
+            ce = layers.cross_entropy(logits, labels, mask)
+        return ce + aux, {"ce": ce, "aux": aux}
 
 
 # --------------------------------------------------------------- decode

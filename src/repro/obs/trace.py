@@ -17,6 +17,22 @@ innermost open span at compile time absorbs the seconds into its
 from the trace alone). With no span open the listener is a no-op, so
 installation is safe process-wide.
 
+Profiler clock
+--------------
+While the tracer is enabled, each span also enters a
+``jax.profiler.TraceAnnotation`` named ``gauntlet.<cat>.<name>``, so an
+active ``jax.profiler`` trace holds the round, stage and dispatch spans
+on its host plane beside the device's operations. With no profiler
+trace running an annotation costs one check.
+
+Named scopes
+------------
+The ``SCOPE_*`` names label the work of the peer's DeMo step in the
+compiled program (``jax.named_scope`` at the function that does it), so
+each HLO instruction's ``op_name`` metadata says which stage it belongs
+to, in the peer's step and in the validator's entry points alike.
+Scopes are trace-time metadata: they add no operation.
+
 Export is the Chrome trace event format (``ph: "X"`` complete events +
 ``ph: "C"`` counters + thread-name metadata), loadable in Perfetto
 (https://ui.perfetto.dev) or ``about:tracing``. Each span's ``tid`` is
@@ -31,6 +47,17 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
+
+# ---------------------------------------------------------- named scopes
+SCOPE_MODEL = "model"                  # loss_fn / forward; backward is
+                                       # what autodiff puts under transpose(
+SCOPE_ACCUMULATE = "model.accumulate"  # the micro-batch gradient sum
+SCOPE_ENCODE = "demo.encode"           # error-feedback accumulate, DCT
+SCOPE_TOPK = "demo.topk"               # magnitude and top-k selection
+SCOPE_DECODE = "demo.decode"           # scatter, inverse DCT, residual
+SCOPE_APPLY = "demo.apply"             # normalise, sign, update
+
+ANNOTATION_PREFIX = "gauntlet."
 
 # ---------------------------------------------------------------- stack
 # per-thread stack of open spans; the compile listener reads the top
@@ -77,7 +104,8 @@ class Span:
     """One open (or closed) trace span. Created via ``SpanTracer``."""
 
     __slots__ = ("name", "cat", "tid", "ts_us", "dur_us", "compile_s",
-                 "compile_events", "args", "_tracer", "_thread")
+                 "compile_events", "args", "_tracer", "_thread",
+                 "_annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str,
                  tid: str, ts_us: float, args: Optional[Dict] = None):
@@ -91,6 +119,7 @@ class Span:
         self.args = dict(args or {})
         self._tracer = tracer
         self._thread = threading.get_ident()
+        self._annotation = None
 
 
 class SpanTracer:
@@ -146,7 +175,11 @@ class SpanTracer:
         attribution stack removes by identity, not LIFO pop."""
         if not self.enabled:
             return None
+        import jax
         span = Span(self, name, cat, tid, self._now_us(), args)
+        span._annotation = jax.profiler.TraceAnnotation(
+            f"{ANNOTATION_PREFIX}{cat}.{name}")
+        span._annotation.__enter__()
         _stack().append(span)
         return span
 
@@ -154,6 +187,7 @@ class SpanTracer:
         if span is None or not self.enabled:
             return
         span.dur_us = self._now_us() - span.ts_us
+        span._annotation.__exit__(None, None, None)
         spans = _stack() if threading.get_ident() == span._thread else None
         if spans is not None and span in spans:
             spans.remove(span)

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.demo import dct
+from repro.obs import trace
 from repro.schemes import GradScheme, register_scheme
 
 
@@ -45,17 +46,20 @@ def _is_payload(x) -> bool:
 
 def topk_compress(coeffs: jnp.ndarray, k: int) -> Payload:
     """coeffs: (num_chunks, s*s) -> top-|k| by magnitude per chunk."""
-    mag = jnp.abs(coeffs)
-    _, idx = jax.lax.top_k(mag, k)
-    vals = jnp.take_along_axis(coeffs, idx, axis=-1)
-    return Payload(vals=vals, idx=idx.astype(jnp.int32))
+    with jax.named_scope(trace.SCOPE_TOPK):
+        mag = jnp.abs(coeffs)
+        _, idx = jax.lax.top_k(mag, k)
+        vals = jnp.take_along_axis(coeffs, idx, axis=-1)
+        return Payload(vals=vals, idx=idx.astype(jnp.int32))
 
 
 def topk_decompress(p: Payload, chunk_elems: int) -> jnp.ndarray:
     """Payload -> dense (num_chunks, s*s) coefficient grid (zeros filled)."""
     nc = p.vals.shape[0]
-    out = jnp.zeros((nc, chunk_elems), jnp.float32)
-    return out.at[jnp.arange(nc)[:, None], p.idx].set(p.vals.astype(jnp.float32))
+    with jax.named_scope(trace.SCOPE_DECODE):
+        out = jnp.zeros((nc, chunk_elems), jnp.float32)
+        return out.at[jnp.arange(nc)[:, None], p.idx].set(
+            p.vals.astype(jnp.float32))
 
 
 # ------------------------------------------------------------- tree utils
@@ -187,11 +191,13 @@ def local_step(grads, state: DemoState, *, beta: float, chunk: int,
     metas = metas or tree_meta(grads, chunk)
 
     def per_leaf(e, g, m):
-        e = beta * e.astype(jnp.float32) + g.astype(jnp.float32)
-        coeffs = (encode_fn or dct.encode)(e, m)
+        with jax.named_scope(trace.SCOPE_ENCODE):
+            e = beta * e.astype(jnp.float32) + g.astype(jnp.float32)
+            coeffs = (encode_fn or dct.encode)(e, m)
         payload = topk_compress(coeffs, k)
         z = dct.decode(topk_decompress(payload, m.s * m.s), m)
-        e_new = e - z
+        with jax.named_scope(trace.SCOPE_DECODE):
+            e_new = e - z
         return payload, e_new
 
     flat_e, treedef = jax.tree.flatten(state.ef)
@@ -220,27 +226,33 @@ def aggregate(payloads, metas, weights: Optional[jnp.ndarray] = None,
     if weights is None:
         weights = jnp.full((K,), 1.0 / K, jnp.float32)
 
-    if normalize:
-        # per-peer global L2 over the stacked payload (DCT domain)
-        sq = sum(jnp.sum(p.vals.astype(jnp.float32) ** 2,
-                         axis=tuple(range(1, p.vals.ndim)))
-                 for p in jax.tree.leaves(stacked, is_leaf=_is_payload))
-        inv = 1.0 / (jnp.sqrt(sq) + 1e-12)                    # (K,)
-    else:
-        inv = jnp.ones((K,), jnp.float32)
-    w = (weights * inv).astype(jnp.float32)                   # (K,)
+    with jax.named_scope(trace.SCOPE_APPLY):
+        if normalize:
+            # per-peer global L2 over the stacked payload (DCT domain)
+            sq = sum(jnp.sum(p.vals.astype(jnp.float32) ** 2,
+                             axis=tuple(range(1, p.vals.ndim)))
+                     for p in jax.tree.leaves(stacked, is_leaf=_is_payload))
+            inv = 1.0 / (jnp.sqrt(sq) + 1e-12)                # (K,)
+        else:
+            inv = jnp.ones((K,), jnp.float32)
+        w = (weights * inv).astype(jnp.float32)               # (K,)
 
     def combine(p: Payload, m: dct.ChunkMeta):
         from repro import hints
         nc, k = p.vals.shape[1], p.vals.shape[2]
-        grid = jnp.zeros((nc, m.s * m.s), jnp.float32)
-        # scatter-add all peers' weighted coefficients into one dense grid
-        rows = jnp.broadcast_to(jnp.arange(nc)[None, :, None], p.idx.shape)
-        grid = grid.at[rows, p.idx].add(
-            p.vals.astype(jnp.float32) * w[:, None, None])
-        grid = hints.constrain_chunks(grid)   # keep the dense fp32 grid
-        delta = dct.decode(grid, m)           # sharded (no-op on hosts)
-        return jnp.sign(delta) if apply_sign else delta
+        with jax.named_scope(trace.SCOPE_DECODE):
+            grid = jnp.zeros((nc, m.s * m.s), jnp.float32)
+            # scatter-add all peers' weighted coefficients into one grid
+            rows = jnp.broadcast_to(jnp.arange(nc)[None, :, None],
+                                    p.idx.shape)
+            grid = grid.at[rows, p.idx].add(
+                p.vals.astype(jnp.float32) * w[:, None, None])
+            grid = hints.constrain_chunks(grid)   # keep the dense fp32 grid
+        delta = dct.decode(grid, m)               # sharded (no-op on hosts)
+        if not apply_sign:
+            return delta
+        with jax.named_scope(trace.SCOPE_APPLY):
+            return jnp.sign(delta)
 
     return jax.tree.map(combine, stacked, metas, is_leaf=_is_payload)
 
@@ -252,7 +264,8 @@ def apply_update(params, delta, lr, weight_decay: float = 0.0):
         if weight_decay:
             p32 = p32 * (1.0 - lr * weight_decay)
         return (p32 - lr * d.astype(jnp.float32)).astype(p.dtype)
-    return jax.tree.map(upd, params, delta)
+    with jax.named_scope(trace.SCOPE_APPLY):
+        return jax.tree.map(upd, params, delta)
 
 
 def aggregate_apply(params, stacked, rows, lr, weights=None, *, metas,

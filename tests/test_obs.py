@@ -3,6 +3,7 @@ with XLA compile attribution, flight-recorder passivity (zero added
 compiles, byte-identical telemetry), per-peer verdict explains, and the
 stdlib telemetry daemon's HTTP/SSE endpoints."""
 import json
+import pathlib
 import urllib.request
 
 import jax
@@ -299,3 +300,45 @@ def test_daemon_sse_replays_backlog(runs):
         assert [r["round"] for r in records] == list(range(ROUNDS))
     finally:
         service.stop()
+
+
+# ------------------------------------------------------ profiler clock
+
+ENTRY_POINTS = ("primary", "baselines", "sync_scores", "fingerprint",
+                "sketch")
+
+
+def test_entry_points_compile_to_distinct_modules(runs):
+    for v in runs["obs"].validators.values():
+        names = [getattr(v, "_" + e).__name__ for e in ENTRY_POINTS]
+        assert names == [f"validator_{e}" for e in ENTRY_POINTS]
+        assert set(v.trace_counts) <= set(ENTRY_POINTS)
+
+
+@pytest.fixture(scope="module")
+def profiled_round(tmp_path_factory):
+    """One traced round under a ``jax.profiler`` trace, read back."""
+    from jax.profiler import ProfileData
+    log_dir = str(tmp_path_factory.mktemp("profile"))
+    engine = SimEngine.from_scenario(
+        get_scenario("byzantine_wave", rounds=1, seed=7), CFG, batch=2,
+        seq_len=32, obs=FlightRecorder(trace=True))
+    jax.profiler.start_trace(log_dir)
+    try:
+        engine.run()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(pathlib.Path(log_dir).rglob("*.xplane.pb"))
+    return [e.name for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_stage_spans_on_profiler_clock(profiled_round):
+    names = set(profiled_round)
+    for stage in ("fast_filter", "primary_eval", "aggregate"):
+        assert f"gauntlet.stage.{stage}" in names
+    assert "gauntlet.round.round-0" in names
+    # each jitted entry point dispatched under its own name
+    assert "PjitFunction(validator_primary)" in names
+    assert "PjitFunction(validator_sync_scores)" in names
