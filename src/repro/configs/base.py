@@ -16,15 +16,40 @@ FAMILIES = ("dense", "moe", "ssm", "hybrid", "vlm", "audio")
 
 @dataclass(frozen=True)
 class MoEConfig:
-    """Mixture-of-experts sub-config (DeepSeekMoE-style fine-grained)."""
+    """Mixture-of-experts sub-config (DeepSeekMoE-style fine-grained).
 
-    num_experts: int = 0          # routed experts
+    The router scores all ``num_experts``; a chip holds the
+    ``experts_held`` of them that start at ``expert_offset`` (expert
+    parallelism) and computes their part of the layer's result."""
+
+    num_experts: int = 0          # routed experts (the router's outputs)
     num_shared_experts: int = 0   # always-on shared experts
     top_k: int = 0                # routed experts per token
     expert_d_ff: int = 0          # per-expert FFN width
-    capacity_factor: float = 1.25
-    router_aux_coef: float = 0.001  # load-balance auxiliary loss coefficient
+    router_aux_coef: float = 0.001  # sequence-wise balance loss alpha
     first_dense_layers: int = 1   # DeepSeek keeps layer 0 dense
+    experts_held: int = 0         # experts held here; 0 = all of them
+    expert_offset: int = 0        # the first expert held here
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (arXiv:2309.00071) with DeepSeek-V2's keys:
+    ``factor`` stretches the ``original_max_position`` positions the
+    model was pre-trained on; ``beta_fast`` and ``beta_slow`` bound the
+    band of frequencies interpolated; the attention scale takes
+    ``mscale_all_dim``'s factor squared (``repro.models.layers``)."""
+
+    factor: float = 1.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -80,6 +105,7 @@ class ModelConfig:
     vocab_size: int = 4096
     max_seq_len: int = 8192
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None   # None = plain rope
     norm_eps: float = 1e-5
     qkv_bias: bool = False            # qwen2-style
     tie_embeddings: bool = False
@@ -125,6 +151,10 @@ class ModelConfig:
                 f"{self.num_kv_heads}")
         if self.family in ("moe",):
             assert self.moe is not None and self.moe.num_experts > 0
+            m = self.moe
+            assert 0 < m.held and m.expert_offset + m.held <= m.num_experts, (
+                f"{self.name}: holds experts [{m.expert_offset}, "
+                f"{m.expert_offset + m.held}) of {m.num_experts}")
         if self.family in ("ssm", "hybrid"):
             assert self.ssm is not None
         if self.family in ("vlm", "audio"):
@@ -162,7 +192,7 @@ class ModelConfig:
                 dense_ffn = 3 * d * self.d_ff
                 expert_ffn = 3 * d * m.expert_d_ff
                 moe_layers = L - m.first_dense_layers
-                per_layer_moe = (m.num_experts + m.num_shared_experts) * expert_ffn + d * m.num_experts
+                per_layer_moe = (m.held + m.num_shared_experts) * expert_ffn + d * m.num_experts
                 # average: dense layers use dense ffn
                 total_ffn = (m.first_dense_layers * dense_ffn + moe_layers * per_layer_moe) / L
                 per_layer += int(total_ffn)
@@ -171,7 +201,8 @@ class ModelConfig:
         return int(emb + L * per_layer)
 
     def active_param_count(self) -> int:
-        """Active params per token (MoE: shared + top_k routed)."""
+        """Active params per token (MoE: shared + the top_k routed, of
+        which a chip holding a share of the experts sees its share)."""
         if self.moe is None or not self.moe.num_experts:
             return self.param_count()
         m = self.moe
@@ -179,7 +210,8 @@ class ModelConfig:
         full = self.param_count()
         expert_ffn = 3 * d * m.expert_d_ff
         moe_layers = L - m.first_dense_layers
-        inactive = moe_layers * (m.num_experts - m.top_k) * expert_ffn
+        inactive = (moe_layers * m.held * (1 - m.top_k / m.num_experts)
+                    * expert_ffn)
         return int(full - inactive)
 
     def with_overrides(self, **kw) -> "ModelConfig":

@@ -1,8 +1,10 @@
 """Architecture registry: ``get_config(arch_id)`` + reduced smoke variants.
 
 Reduced variants keep the *family-defining structure* (GQA ratio, MoE
-routing, SSM heads, stub frontends, cross-attention) at ≤2 layers,
-d_model ≤ 512, ≤4 experts so they run a real step on one CPU device.
+routing, shared experts and leading dense layers, latent attention with
+or without a query low rank, YaRN, SSM heads, stub frontends,
+cross-attention) at ≤2 layers, d_model ≤ 512, ≤4 experts so they run a
+real step on one CPU device.
 """
 from __future__ import annotations
 
@@ -22,6 +24,7 @@ _ARCH_MODULES = {
     "internvl2-2b": "repro.configs.internvl2_2b",
     "whisper-base": "repro.configs.whisper_base",
     "deepseek-v2-236b": "repro.configs.deepseek_v2_236b",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
     "yi-6b": "repro.configs.yi_6b",
     "deepseek-moe-16b": "repro.configs.deepseek_moe_16b",
     "h2o-danube-3-4b": "repro.configs.h2o_danube_3_4b",
@@ -68,7 +71,8 @@ def reduced_config(arch: str) -> ModelConfig:
                               expert_d_ff=128,
                               first_dense_layers=cfg.moe.first_dense_layers)
     if cfg.mla is not None:
-        kw["mla"] = MLAConfig(kv_lora_rank=64, q_lora_rank=48,
+        kw["mla"] = MLAConfig(kv_lora_rank=64,
+                              q_lora_rank=48 if cfg.mla.q_lora_rank else 0,
                               qk_rope_head_dim=16, qk_nope_head_dim=32,
                               v_head_dim=32)
     if cfg.ssm is not None:

@@ -13,10 +13,6 @@ from jax.sharding import PartitionSpec as P
 
 _HEAD_AXIS: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
     "repro_head_axis", default=None)
-_EXPERT_AXIS: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
-    "repro_expert_axis", default=None)
-_EXPERT_F_AXIS: contextvars.ContextVar[Optional[str]] = (
-    contextvars.ContextVar("repro_expert_f_axis", default=None))
 
 
 _CHUNK_AXES: contextvars.ContextVar[Optional[tuple]] = contextvars.ContextVar(
@@ -24,17 +20,13 @@ _CHUNK_AXES: contextvars.ContextVar[Optional[tuple]] = contextvars.ContextVar(
 
 
 @contextlib.contextmanager
-def axis_hints(head: Optional[str] = None, expert: Optional[str] = None,
-               expert_f: Optional[str] = None, chunk: Optional[tuple] = None):
-    toks = (_HEAD_AXIS.set(head), _EXPERT_AXIS.set(expert),
-            _EXPERT_F_AXIS.set(expert_f), _CHUNK_AXES.set(chunk))
+def axis_hints(head: Optional[str] = None, chunk: Optional[tuple] = None):
+    toks = (_HEAD_AXIS.set(head), _CHUNK_AXES.set(chunk))
     try:
         yield
     finally:
         _HEAD_AXIS.reset(toks[0])
-        _EXPERT_AXIS.reset(toks[1])
-        _EXPERT_F_AXIS.reset(toks[2])
-        _CHUNK_AXES.reset(toks[3])
+        _CHUNK_AXES.reset(toks[1])
 
 
 def constrain_chunks(x):
@@ -75,21 +67,25 @@ def per_chunk_shard(fn, x):
                          check_vma=False)(x)
 
 
-def constrain_moe(x, hidden: bool = False):
-    """Hint for MoE dispatch buffers (G,E,C,d) / (G,E,C,f): expert dim
-    over the expert-parallel axis (the all-to-all boundary); the hidden
-    f dim over the expert-TP axis. No-op outside a step context."""
-    e_ax = _EXPERT_AXIS.get()
-    if e_ax is None:
-        return x
-    f_ax = _EXPERT_F_AXIS.get() if hidden else None
-    if f_ax == e_ax:
-        f_ax = None
-    try:
-        spec = P(*([None] * (x.ndim - 3) + [e_ax, None, f_ax]))
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x
+def per_group_shard(fn, shared, *grouped):
+    """``fn(shared, *grouped)`` for arrays ``grouped`` whose leading axis
+    holds independent token groups (the expert layer's dispatch groups).
+    Under a mesh whose axes not yet manual, 'model' aside, split the
+    groups evenly, ``fn`` runs in a ``shard_map`` over those axes, each
+    device taking its own groups, so no group's rows leave their device;
+    ``shared`` enters whole and 'model' stays with the partitioner."""
+    mesh = jax.sharding.get_abstract_mesh()
+    free = tuple(a for a in mesh.axis_names
+                 if a not in mesh.manual_axes and a != "model")
+    size = 1
+    for a in free:
+        size *= mesh.shape[a]
+    if not free or grouped[0].shape[0] % size:
+        return fn(shared, *grouped)
+    spec = P(free)
+    return jax.shard_map(fn, in_specs=(P(),) + (spec,) * len(grouped),
+                         out_specs=spec, axis_names=set(free),
+                         check_vma=False)(shared, *grouped)
 
 
 def constrain_heads(x):

@@ -165,23 +165,12 @@ def make_grad_fn(loss_of, microbatch: int):
     return grad_of
 
 
-def step_hints(cfg: ModelConfig, mesh) -> Dict[str, Optional[str]]:
-    """Axis hints published to model-code sharding constraints: attention
-    heads over 'model'; MoE expert dim over the secondary tp axis (EP with
-    all-to-all dispatch) and expert-ffn over the primary (TP) — matching
-    sharding._param_rule's expert-bank layout."""
-    h: Dict[str, Optional[str]] = {"head": "model"}
-    tp = sh.tp_axes(cfg, mesh)
-    # NOTE: "chunk" constraints measured WORSE (§Perf B2: they add
-    # resharding churn on top of the upstream replication instead of
-    # preventing it) — the fix that worked is the flatten-free reshape in
-    # dct.to_chunks/from_chunks (B3). Hint left off.
-    h["chunk"] = None
-    if cfg.moe is not None and cfg.moe.num_experts:
-        t2 = tp[1] if len(tp) > 1 else None
-        h["expert"] = t2 or (tp[0] if tp else None)
-        h["expert_f"] = tp[0] if t2 else None
-    return h
+# Axis hints published to model-code sharding constraints: attention
+# heads over 'model'. "chunk" constraints measured WORSE (§Perf B2: they
+# add resharding churn on top of the upstream replication instead of
+# preventing it) — the fix that worked is the flatten-free reshape in
+# dct.to_chunks/from_chunks (B3). Hint left off.
+STEP_HINTS: Dict[str, Optional[str]] = {"head": "model", "chunk": None}
 
 
 def _inner_groups(cfg: ModelConfig, mesh) -> int:
@@ -353,7 +342,7 @@ def make_demo_train_step(cfg: ModelConfig, hp: TrainConfig, mesh,
             cfg, mesh, name=f"demo_train[{cfg.name}|{shape.name}]",
             per_peer=per_peer, p_sds=p_sds, pspecs=pspecs,
             state_sds=ef_sds, state_specs=efspecs, batch_sds=batch_sds,
-            donate=donate, hints=step_hints(cfg, mesh))
+            donate=donate, hints=STEP_HINTS)
 
     # ---- degenerate single peer (e.g. deepseek-v2 on one pod):
     # gradient over the whole mesh (GSPMD all-reduces over 'data'); the
@@ -380,7 +369,7 @@ def make_demo_train_step(cfg: ModelConfig, hp: TrainConfig, mesh,
         in_specs=(pspecs, pspecs, bspecs, P()),
         out_specs=(pspecs, pspecs, P()),
         donate=(0, 1) if donate else (),
-        hints=step_hints(cfg, mesh))
+        hints=STEP_HINTS)
 
 
 # ---------------------------------------------------------- any scheme
@@ -456,7 +445,7 @@ def make_scheme_train_step(cfg: ModelConfig, hp: TrainConfig, mesh,
             cfg, mesh, name=name, per_peer=per_peer, p_sds=p_sds,
             pspecs=pspecs, state_sds=state_sds, state_specs=state_specs,
             batch_sds=batch_sds, donate=donate,
-            hints=step_hints(cfg, mesh))
+            hints=STEP_HINTS)
 
     # degenerate single peer: K=1, no collective, same scheme math
     def step1(params, state, batch, step_idx):
@@ -479,7 +468,7 @@ def make_scheme_train_step(cfg: ModelConfig, hp: TrainConfig, mesh,
         in_specs=(pspecs, state_specs, bspecs, P()),
         out_specs=(pspecs, state_specs, P()),
         donate=(0, 1) if donate else (),
-        hints=step_hints(cfg, mesh))
+        hints=STEP_HINTS)
 
 
 # ----------------------------------------------------------------- DDP
@@ -528,7 +517,7 @@ def make_ddp_train_step(cfg: ModelConfig, hp: TrainConfig, mesh,
         in_specs=(pspecs, opt_specs, bspecs, P()),
         out_specs=(pspecs, opt_specs, P()),
         donate=(0, 1) if donate else (),
-        hints=step_hints(cfg, mesh))
+        hints=STEP_HINTS)
 
 
 # ----------------------------------------------------------------- serve
@@ -568,7 +557,7 @@ def make_serve_step(cfg: ModelConfig, mesh, shape: InputShape,
         name=f"serve[{cfg.name}|{shape.name}]", fn=step,
         args=(_sds_like(p_sds), _sds_like(c_sds), tok_sds),
         in_specs=(pspecs, cspecs, tspec),
-        hints=step_hints(cfg, mesh))
+        hints=STEP_HINTS)
 
 
 def make_prefill_step(cfg: ModelConfig, mesh, shape: InputShape,
@@ -591,7 +580,7 @@ def make_prefill_step(cfg: ModelConfig, mesh, shape: InputShape,
         name=f"prefill[{cfg.name}|{shape.name}]", fn=step,
         args=(_sds_like(p_sds), batch_sds),
         in_specs=(pspecs, bspecs),
-        hints=step_hints(cfg, mesh))
+        hints=STEP_HINTS)
 
 
 # ----------------------------------------------------------------- picker

@@ -10,6 +10,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # ----------------------------------------------------------------- init
 
@@ -69,16 +70,65 @@ def init_swiglu(key, d, d_ff, dtype):
 # ----------------------------------------------------------------- rope
 
 
-def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> jnp.ndarray:
+    """Rotary inverse frequencies; YaRN's where ``scaling`` (a
+    ``configs.base.YarnConfig``) is given."""
+    if scaling is not None:
+        return jnp.asarray(yarn_inv_freq(head_dim, theta, scaling))
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction: 0.1·mscale·ln(factor) + 1 (1 where
+    the positions are not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, y) -> np.ndarray:
+    """YaRN's inverse frequencies (DeepSeek-V2's form), for ``dim``
+    rotated dimensions:
+
+        f_extra = θ^(−2i/dim)            the pre-trained frequencies
+        f_inter = f_extra / factor       stretched over factor × positions
+        inv_freq = f_inter·(1 − m) + f_extra·m,   m = 1 − ramp(low, high)
+
+    ramp rises linearly from 0 at pair ``low`` to 1 at pair ``high``
+    (clipped); [low, high] is the band of pairs whose wavelengths make
+    between ``beta_fast`` and ``beta_slow`` turns over the
+    ``original_max_position`` pre-trained positions:
+
+        d(r) = dim·ln(L / (2π r)) / (2 ln θ)
+        low = max(⌊d(beta_fast)⌋, 0),  high = min(⌈d(beta_slow)⌉, dim − 1)
+    """
+    def turns(r):
+        return (dim * math.log(y.original_max_position / (r * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns(y.beta_fast)), 0)
+    high = min(math.ceil(turns(y.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    m = 1.0 - ramp
+    return (extra / y.factor * (1.0 - m) + extra * m).astype(np.float32)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               scaling=None) -> jnp.ndarray:
+    """x: (..., seq, heads, head_dim); positions: (..., seq). With YaRN
+    ``scaling``, cos and sin are also multiplied by
+    yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                        # (hd/2,)
+    freqs = rope_freqs(hd, theta, scaling)               # (hd/2,)
     ang = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    if scaling is not None:
+        gain = (yarn_mscale(scaling.factor, scaling.mscale)
+                / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if gain != 1.0:
+            cos, sin = cos * gain, sin * gain
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -1,5 +1,23 @@
 """Multi-head Latent Attention (DeepSeek-V2, arXiv:2405.04434).
 
+Per token x (d) and head h, with q_lora_rank 0 (DeepSeek-V2-Lite) or a
+normed low-rank query otherwise:
+
+    [q_nope_h, q_rope_h] = (x W_q)_h                  128 + 64 wide
+    [c, k_rope] = x W_kv_a;   c <- RMSNorm(c)          512 + 64 wide
+    [k_nope_h, v_h] = (c W_kv_b)_h                     128 + 128 wide
+    q_rope, k_rope <- rope(·, positions)               k_rope shared by
+                                                       every head
+    o_h = softmax(scale·(q_nope_h·k_nope_h + q_rope_h·k_rope), causal) v_h
+    out = [o_1 .. o_H] W_o
+
+scale = (nope + rope)^(-1/2), times yarn_mscale(factor,
+mscale_all_dim)^2 where the config sets YaRN rope scaling (cos and sin
+then take ``layers.apply_rope``'s gain, 1 for DeepSeek-V2). Departure:
+DeepSeek rotates interleaved pairs of the rope dims; this rotates the
+two halves, which is the same map after a fixed permutation of the rope
+columns of W_q and W_kv_a (on random weights, the same model).
+
 Train/prefill: naive (expand latent to per-head K/V).
 Decode: *absorbed* form — W_uk is folded into the query and W_uv into the
 output so each step attends directly over the (S, r) latent cache plus the
@@ -16,6 +34,7 @@ import jax.numpy as jnp
 
 from repro import hints
 from repro.models import layers
+from repro.obs import trace
 
 NEG_INF = -1e30
 
@@ -60,7 +79,8 @@ def _project_q(p, x, cfg, positions):
     q = layers.linear(p["wq_b"], q_in)
     q = q.reshape(*x.shape[:-1], H, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta,
+                               cfg.rope_scaling)
     return q_nope, q_rope
 
 
@@ -71,13 +91,27 @@ def _latent_kv(p, x, cfg, positions):
     c_kv = layers.rmsnorm(p["kv_norm"], c_kv, cfg.norm_eps)
     # shared single-head rope key, rotated at absolute positions
     k_rope = layers.apply_rope(k_rope[..., None, :], positions,
-                               cfg.rope_theta)[..., 0, :]
+                               cfg.rope_theta, cfg.rope_scaling)[..., 0, :]
     return c_kv, k_rope
+
+
+def softmax_scale(cfg) -> float:
+    """(nope + rope)^(-1/2), times YaRN's mscale(mscale_all_dim)^2."""
+    m, y = cfg.mla, cfg.rope_scaling
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    if y is not None and y.mscale_all_dim:
+        scale *= layers.yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
 
 def attend_full(p, x, cfg, q_block: int = 512):
     """Naive expanded MLA for train/prefill, q-row-blocked (the fp32 score
     buffer is (B,H,q_block,S), jax.checkpoint'ed per block). x: (B,S,d)."""
+    with jax.named_scope(trace.BLOCK_MLA):
+        return _attend_full(p, x, cfg, q_block)
+
+
+def _attend_full(p, x, cfg, q_block: int):
     B, S, _ = x.shape
     m, H = cfg.mla, cfg.num_heads
     positions = jnp.arange(S)[None, :]
@@ -88,7 +122,7 @@ def attend_full(p, x, cfg, q_block: int = 512):
     k_nope, v = jnp.split(kvb, [m.qk_nope_head_dim], axis=-1)
     q_nope, q_rope, k_nope, v = map(hints.constrain_heads,
                                     (q_nope, q_rope, k_nope, v))
-    scale = 1.0 / jnp.sqrt(jnp.float32(m.qk_nope_head_dim + m.qk_rope_head_dim))
+    scale = softmax_scale(cfg)
 
     def block(qn, qr, offset):
         scores = (jnp.einsum("bqhd,bkhd->bhqk", qn, k_nope)
@@ -145,7 +179,7 @@ def attend_decode(p, x, cache: MLACache, cfg):
     W_uk = Wk[..., :m.qk_nope_head_dim]                    # (r,H,nope)
     W_uv = Wk[..., m.qk_nope_head_dim:]                    # (r,H,v)
     q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, W_uk)
-    scale = 1.0 / jnp.sqrt(jnp.float32(m.qk_nope_head_dim + m.qk_rope_head_dim))
+    scale = softmax_scale(cfg)
     ckv = c_kv.astype(x.dtype)
     scores = (jnp.einsum("bqhr,bkr->bhqk", q_lat, ckv)
               + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope.astype(x.dtype)))

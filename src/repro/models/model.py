@@ -116,8 +116,9 @@ def _embed_inputs(params, batch, cfg: ModelConfig):
 
 
 def _block_seq(blk, x, cfg: ModelConfig, li: int, enc_kv, num_groups: int):
-    """Full-sequence block application. Returns (x, aux_loss)."""
-    aux = jnp.float32(0.0)
+    """Full-sequence block application. Returns (x, aux_loss, counts):
+    an expert layer's ``moe.moe_ffn`` counts, None for other layers."""
+    aux, counts = jnp.float32(0.0), None
     if cfg.family == "ssm":
         tm, _ = rwkv6.time_mix(blk["time_mix"], layers.rmsnorm(blk["norm1"], x,
                                                                cfg.norm_eps),
@@ -126,7 +127,7 @@ def _block_seq(blk, x, cfg: ModelConfig, li: int, enc_kv, num_groups: int):
         x = x + rwkv6.channel_mix_seq(blk["channel_mix"],
                                       layers.rmsnorm(blk["norm2"], x,
                                                      cfg.norm_eps))
-        return x, aux
+        return x, aux, counts
 
     h = layers.rmsnorm(blk["norm1"], x, cfg.norm_eps)
     w = layer_window(cfg, li)
@@ -148,16 +149,23 @@ def _block_seq(blk, x, cfg: ModelConfig, li: int, enc_kv, num_groups: int):
                                        enc_kv, cfg)
     h2 = layers.rmsnorm(blk["norm2"], x, cfg.norm_eps)
     if "moe" in blk:
-        ffn_out, aux = moe.moe_ffn(blk["moe"], h2, cfg, num_groups=num_groups)
+        ffn_out, aux, counts = moe.moe_ffn(blk["moe"], h2, cfg,
+                                           num_groups=num_groups)
     else:
         ffn_out = layers.swiglu(blk["mlp"], h2)
-    return x + ffn_out, aux
+    return x + ffn_out, aux, counts
+
+
+def _moe_counts(per_layer):
+    """The expert layers' counts, (layers, 2), or None without any."""
+    per_layer = [jnp.atleast_2d(c) for c in per_layer if c is not None]
+    return jnp.concatenate(per_layer) if per_layer else None
 
 
 def _trunk(params, batch, cfg: ModelConfig, num_groups: int,
            remat: bool = False):
     x, offset, enc = _embed_inputs(params, batch, cfg)
-    aux_total = jnp.float32(0.0)
+    aux_total, counts = jnp.float32(0.0), []
     for li, blk in enumerate(params["layers"]):
         enc_kv = None
         if cfg.cross_attention and enc is not None:
@@ -166,10 +174,11 @@ def _trunk(params, batch, cfg: ModelConfig, num_groups: int,
                                num_groups=num_groups)
         if remat:
             fn = jax.checkpoint(fn)
-        x, aux = fn(blk, x)
+        x, aux, c = fn(blk, x)
         aux_total = aux_total + aux
+        counts.append(c)
     x = layers.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return x, offset, aux_total
+    return x, offset, aux_total, _moe_counts(counts)
 
 
 def _unembed(params, x, cfg: ModelConfig):
@@ -224,7 +233,7 @@ def init_params_stacked(cfg: ModelConfig, key):
 def _trunk_stacked(params, batch, cfg: ModelConfig, num_groups: int,
                    remat: bool = False):
     x, offset, enc = _embed_inputs(params, batch, cfg)
-    aux_total = jnp.float32(0.0)
+    aux_total, counts = jnp.float32(0.0), []
     for (start, n), blk in zip(layer_groups(cfg), params["groups"]):
         def apply_one(blk_l, x_in):
             enc_kv = None
@@ -237,24 +246,26 @@ def _trunk_stacked(params, batch, cfg: ModelConfig, num_groups: int,
             return fn(blk_l, x_in)
 
         if n == 1:
-            x, aux = apply_one(blk, x)
+            x, aux, c = apply_one(blk, x)
             aux_total = aux_total + aux
+            counts.append(c)
         else:
             def body(carry, blk_l):
                 x_c, aux_c = carry
-                x2, a = apply_one(blk_l, x_c)
-                return (x2, aux_c + a), None
+                x2, a, c = apply_one(blk_l, x_c)
+                return (x2, aux_c + a), c
 
-            (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), blk)
+            (x, aux_total), c = jax.lax.scan(body, (x, aux_total), blk)
+            counts.append(c)
     x = layers.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return x, offset, aux_total
+    return x, offset, aux_total, _moe_counts(counts)
 
 
 def forward(params, batch, cfg: ModelConfig, num_groups: int = 1,
             remat: bool = False, scan_layers: bool = False):
     trunk = _trunk_stacked if scan_layers else _trunk
     with jax.named_scope(trace.SCOPE_MODEL):
-        x, offset, _ = trunk(params, batch, cfg, num_groups, remat)
+        x, offset, _, _ = trunk(params, batch, cfg, num_groups, remat)
         logits = _unembed(params, x, cfg)
         if offset:
             logits = logits[:, offset:]
@@ -264,10 +275,13 @@ def forward(params, batch, cfg: ModelConfig, num_groups: int = 1,
 def loss_fn(params, batch, cfg: ModelConfig, num_groups: int = 1,
             remat: bool = False, ce_chunks: int = 0,
             scan_layers: bool = False):
-    """Next-token LM loss. Returns (loss, metrics)."""
+    """Next-token LM loss. Returns (loss, metrics): ``ce``, ``aux`` and,
+    with expert layers, ``moe_held`` and ``moe_max_load`` (per expert
+    layer: the assignments that fell on held experts, and the most that
+    fell on one of them)."""
     trunk = _trunk_stacked if scan_layers else _trunk
     with jax.named_scope(trace.SCOPE_MODEL):
-        x, offset, aux = trunk(params, batch, cfg, num_groups, remat)
+        x, offset, aux, counts = trunk(params, batch, cfg, num_groups, remat)
         if offset:
             x = x[:, offset:]
         labels = batch["labels"]
@@ -280,7 +294,10 @@ def loss_fn(params, batch, cfg: ModelConfig, num_groups: int = 1,
         else:
             logits = _unembed(params, x, cfg)
             ce = layers.cross_entropy(logits, labels, mask)
-        return ce + aux, {"ce": ce, "aux": aux}
+        metrics = {"ce": ce, "aux": aux}
+        if counts is not None:
+            metrics.update(moe_held=counts[:, 0], moe_max_load=counts[:, 1])
+        return ce + aux, metrics
 
 
 # --------------------------------------------------------------- decode
@@ -363,7 +380,7 @@ def _block_decode(blk, x, c, cfg: ModelConfig, li: int, cross_kv_li,
                                        cross_kv_li, cfg)
     h2 = layers.rmsnorm(blk["norm2"], x, cfg.norm_eps)
     if "moe" in blk:
-        ffn_out, _ = moe.moe_ffn(blk["moe"], h2, cfg, num_groups=num_groups)
+        ffn_out, _, _ = moe.moe_ffn(blk["moe"], h2, cfg, num_groups=num_groups)
     else:
         ffn_out = layers.swiglu(blk["mlp"], h2)
     return x + ffn_out, new_c

@@ -31,7 +31,9 @@ The ``SCOPE_*`` names label the work of the peer's DeMo step in the
 compiled program (``jax.named_scope`` at the function that does it), so
 each HLO instruction's ``op_name`` metadata says which stage it belongs
 to, in the peer's step and in the validator's entry points alike.
-Scopes are trace-time metadata: they add no operation.
+``BLOCK_SCOPES`` label the parts of a latent-attention and expert
+block inside the model's scope. Scopes are trace-time metadata: they
+add no operation.
 
 Export is the Chrome trace event format (``ph: "X"`` complete events +
 ``ph: "C"`` counters + thread-name metadata), loadable in Perfetto
@@ -56,6 +58,19 @@ SCOPE_ENCODE = "demo.encode"           # error-feedback accumulate, DCT
 SCOPE_TOPK = "demo.topk"               # magnitude and top-k selection
 SCOPE_DECODE = "demo.decode"           # scatter, inverse DCT, residual
 SCOPE_APPLY = "demo.apply"             # normalise, sign, update
+
+# The block's parts, nested inside SCOPE_MODEL. A vocabulary of their
+# own: the benchmark's classes of the SCOPE_* names (fwd, bwd, the codec
+# stages) read the innermost SCOPE_* scope, so these leave them as they
+# are, and a reader of the block's split reads these.
+BLOCK_MLA = "mla"                      # latent attention (models/mla.py)
+BLOCK_MOE_ROUTE = "moe.route"          # router logits, top-k, balance loss
+BLOCK_MOE_DISPATCH = "moe.dispatch"    # sort by expert, gather the rows
+BLOCK_MOE_EXPERTS = "moe.experts"      # grouped matmuls of held experts
+BLOCK_MOE_COMBINE = "moe.combine"      # gate-weighted sum back per token
+BLOCK_MOE_SHARED = "moe.shared"        # the shared experts
+BLOCK_SCOPES = (BLOCK_MLA, BLOCK_MOE_ROUTE, BLOCK_MOE_DISPATCH,
+                BLOCK_MOE_EXPERTS, BLOCK_MOE_COMBINE, BLOCK_MOE_SHARED)
 
 ANNOTATION_PREFIX = "gauntlet."
 

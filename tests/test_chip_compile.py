@@ -221,3 +221,60 @@ def test_mesh_step_selects_in_vmem(v5e_2x2, data, model):
     kernels, sorts = _selections(plan.lower(mesh).compile())
     assert kernels == ["topk"] * len(jax.tree.leaves(plan.args[0]))
     assert "topk" not in sorts
+
+
+def _collectives_by_scope(text):
+    """Bytes each scope of the expert layer moves in collectives."""
+    sys.path.insert(0, os.path.join(ROOT, "bench"))
+    import scopecut
+
+    names = scopecut.op_names(text)
+    moved = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+) = (\S+) (all-gather|"
+                     r"all-reduce|reduce-scatter|all-to-all|"
+                     r"collective-permute)(?:-start)?\(", line)
+        if not m:
+            continue
+        scope = next((s for s in ("moe.dispatch", "moe.experts",
+                                  "moe.combine")
+                      if s in (names.get(m.group(1)) or "")), None)
+        if scope:
+            moved[scope] = moved.get(scope, 0) + 1
+    return moved
+
+
+def test_expert_groups_stay_on_their_shard(v5e_2x2):
+    """DeepSeek-V2's peer step on a (data, model) mesh where 'data' splits
+    the tokens within one peer: the expert layer sorts, gathers and sums
+    back each data shard's tokens on its own device (``num_groups``), so
+    its dispatch, products and combine move nothing between chips, and
+    each sort holds one shard's assignments. The DeMo top-k lowers to
+    one kernel call per parameter leaf, the grouped products to kernels
+    of their own."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.configs.base import InputShape, TrainConfig
+    from repro.configs.registry import reduced_config
+    from repro.launch.steps import make_step
+
+    mesh = Mesh(np.array(v5e_2x2).reshape(2, 2), ("data", "model"))
+    cfg = reduced_config("deepseek-v2-lite").with_overrides(
+        peer_axes=("pod",))
+    plan = make_step(cfg, TrainConfig(demo_chunk=16, demo_topk=4), mesh,
+                     InputShape("t", seq_len=64, global_batch=8,
+                                kind="train"),
+                     variant="demo", microbatch=2, scan_layers=True)
+    compiled = plan.lower(mesh).compile()
+    kernels, _ = _selections(compiled)
+    # the rest are the grouped products' kernels, in the model's scopes
+    assert kernels.count("topk") == len(jax.tree.leaves(plan.args[0]))
+    assert set(kernels) == {"topk", "fwd", "bwd"}, set(kernels)
+    text = compiled.as_text()
+    assert _collectives_by_scope(text) == {}
+    # a micro-batch of 4 x 64 tokens, top-2: 512 assignments, 256 a shard
+    sorted_ints = [m.group(1) for m in (
+        re.search(r"= \(s32\[([0-9,]+)\]", line)
+        for line in text.splitlines() if " sort(" in line) if m]
+    assert sorted_ints and set(sorted_ints) == {"1,256"}, sorted_ints

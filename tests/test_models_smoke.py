@@ -2,7 +2,6 @@
 REDUCED variant — one train step and one decode step on CPU, asserting
 output shapes and absence of NaNs. Family-defining structure is preserved
 (GQA ratio, MoE routing, MLA, SSM heads, stub frontends, cross-attn)."""
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -105,13 +104,13 @@ def test_decode_matches_full_forward(arch):
     assert err / scale < 5e-4, (arch, err, scale)
 
 
-@pytest.mark.parametrize("arch", ["deepseek-moe-16b", "deepseek-v2-236b"])
+@pytest.mark.parametrize("arch", ["deepseek-moe-16b", "deepseek-v2-236b",
+                                  "deepseek-v2-lite"])
 def test_decode_matches_full_forward_moe(arch):
-    """MoE parity requires generous expert capacity (drops are the only
-    legal divergence between batched dispatch and per-token decode)."""
+    """The dropless expert layer drops nothing, so per-token decode and
+    the batched full forward agree (latent attention and YaRN in the
+    DeepSeek-V2 entries)."""
     cfg = reduced_config(arch)
-    cfg = cfg.with_overrides(
-        moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
     key = jax.random.PRNGKey(4)
     params = M.init_params(cfg, key)
     S_ = 12
