@@ -67,6 +67,32 @@ def per_chunk_shard(fn, x):
                          check_vma=False)(x)
 
 
+def per_head_shard(fn, *xs):
+    """``fn(*xs)`` for arrays (B, H, ...) whose examples and heads are
+    independent, with ``fn`` one that XLA cannot partition, such as a
+    Pallas kernel. Under a mesh with axes not yet manual, ``fn`` runs in
+    a ``shard_map`` that makes every axis manual (a Mosaic kernel lowers
+    only there): heads split over 'model', examples over the other free
+    axes; a dimension that does not divide evenly stays whole."""
+    mesh = jax.sharding.get_abstract_mesh()
+    free = tuple(a for a in mesh.axis_names if a not in mesh.manual_axes)
+    if not free:
+        return fn(*xs)
+
+    def split(axes, n):
+        size = 1
+        for a in axes:
+            size *= mesh.shape[a]
+        return axes if axes and n % size == 0 else None
+
+    B, H = xs[0].shape[:2]
+    spec = P(split(tuple(a for a in free if a != "model"), B),
+             split(tuple(a for a in free if a == "model"), H))
+    return jax.shard_map(fn, in_specs=spec, out_specs=spec,
+                         axis_names=set(mesh.axis_names),
+                         check_vma=False)(*xs)
+
+
 def per_group_shard(fn, shared, *grouped):
     """``fn(shared, *grouped)`` for arrays ``grouped`` whose leading axis
     holds independent token groups (the expert layer's dispatch groups).

@@ -18,7 +18,11 @@ DeepSeek rotates interleaved pairs of the rope dims; this rotates the
 two halves, which is the same map after a fixed permutation of the rope
 columns of W_q and W_kv_a (on random weights, the same model).
 
-Train/prefill: naive (expand latent to per-head K/V).
+Train/prefill: naive (expand latent to per-head K/V). Compiled for a
+TPU, at a length the kernel's blocks divide, the causal core is the
+Pallas splash kernel: flash attention in VMEM with float32 scores and
+statistics, which skips the blocks above the diagonal; elsewhere it is
+an XLA softmax over 512-row query blocks of the whole square.
 Decode: *absorbed* form — W_uk is folded into the query and W_uv into the
 output so each step attends directly over the (S, r) latent cache plus the
 shared rope key. This is the TPU-native adaptation: the per-step work is a
@@ -27,16 +31,29 @@ re-expanding full K/V (which would cost O(S·r·H·hd) per token).
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from repro import hints
 from repro.models import layers
 from repro.obs import trace
 
 NEG_INF = -1e30
+
+# The splash kernel's blocks. One v5e, the causal core alone at the
+# benchmark cell's micro-batch (4 x 4096, 16 heads, qk 192, v 128):
+# forward 4.94 ms at 512 everywhere, 4.40 at these; forward and backward
+# 18.64 ms at 512 with separate dq and dkv kernels, 15.00 with the fused
+# backward at 1024 (the XLA core: 21.78 and 63.79 ms).
+BLOCK = 1024
+BLOCKS = splash.BlockSizes(
+    block_q=BLOCK, block_kv=BLOCK, block_kv_compute=512,
+    block_q_dkv=BLOCK, block_kv_dkv=BLOCK, block_kv_dkv_compute=BLOCK,
+    use_fused_bwd_kernel=True)
 
 
 class MLACache(NamedTuple):
@@ -105,13 +122,17 @@ def softmax_scale(cfg) -> float:
 
 
 def attend_full(p, x, cfg, q_block: int = 512):
-    """Naive expanded MLA for train/prefill, q-row-blocked (the fp32 score
-    buffer is (B,H,q_block,S), jax.checkpoint'ed per block). x: (B,S,d)."""
+    """Naive expanded MLA for train/prefill. x: (B,S,d). Compiled for a
+    TPU with S a multiple of ``BLOCK``, the causal core is the splash
+    kernel (``_attend_in_vmem``); otherwise it is q-row-blocked (the fp32
+    score buffer is (B,H,q_block,S), jax.checkpoint'ed per block)."""
     with jax.named_scope(trace.BLOCK_MLA):
         return _attend_full(p, x, cfg, q_block)
 
 
-def _attend_full(p, x, cfg, q_block: int):
+def _project_heads(p, x, cfg):
+    """(q_nope, q_rope, k_nope, k_rope, v) of x (B,S,d): (B,S,H,*) each,
+    but the one shared rope key k_rope (B,S,rope)."""
     B, S, _ = x.shape
     m, H = cfg.mla, cfg.num_heads
     positions = jnp.arange(S)[None, :]
@@ -122,7 +143,28 @@ def _attend_full(p, x, cfg, q_block: int):
     k_nope, v = jnp.split(kvb, [m.qk_nope_head_dim], axis=-1)
     q_nope, q_rope, k_nope, v = map(hints.constrain_heads,
                                     (q_nope, q_rope, k_nope, v))
+    return q_nope, q_rope, k_nope, k_rope, v
+
+
+def _attend_full(p, x, cfg, q_block: int):
+    B, S, _ = x.shape
+    heads = _project_heads(p, x, cfg)
     scale = softmax_scale(cfg)
+    blocked = functools.partial(_attend_blocked, scale=scale, q_block=q_block)
+    if S % BLOCK:
+        out = blocked(*heads)
+    else:
+        out = jax.lax.platform_dependent(
+            *heads, default=blocked,
+            tpu=functools.partial(_attend_in_vmem, scale=scale,
+                                  interpret=False))
+    return layers.linear(p["wo"], out.reshape(B, S, -1))
+
+
+def _attend_blocked(q_nope, q_rope, k_nope, k_rope, v, *, scale: float,
+                    q_block: int):
+    """The causal core in XLA: (B,S,H,v) from ``_project_heads``."""
+    B, S, H, _ = q_nope.shape
 
     def block(qn, qr, offset):
         scores = (jnp.einsum("bqhd,bkhd->bhqk", qn, k_nope)
@@ -131,24 +173,50 @@ def _attend_full(p, x, cfg, q_block: int):
         qpos = jnp.arange(qn.shape[1])[:, None] + offset
         mask = (jnp.arange(S)[None, :] <= qpos)[None, None]
         scores = jnp.where(mask, scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
     if S <= 1024 or S % q_block:
-        out = block(q_nope, q_rope, 0)
-    else:
-        nq = S // q_block
-        qn = q_nope.reshape(B, nq, q_block, H, -1).transpose(1, 0, 2, 3, 4)
-        qr = q_rope.reshape(B, nq, q_block, H, -1).transpose(1, 0, 2, 3, 4)
+        return block(q_nope, q_rope, 0)
+    nq = S // q_block
+    qn = q_nope.reshape(B, nq, q_block, H, -1).transpose(1, 0, 2, 3, 4)
+    qr = q_rope.reshape(B, nq, q_block, H, -1).transpose(1, 0, 2, 3, 4)
 
-        @jax.checkpoint
-        def body(carry, inp):
-            qni, qri, i = inp
-            return carry, block(qni, qri, i * q_block)
+    @jax.checkpoint
+    def body(carry, inp):
+        qni, qri, i = inp
+        return carry, block(qni, qri, i * q_block)
 
-        _, outs = jax.lax.scan(body, (), (qn, qr, jnp.arange(nq)))
-        out = outs.transpose(1, 0, 2, 3, 4).reshape(B, S, H, m.v_head_dim)
-    return layers.linear(p["wo"], out.reshape(B, S, H * m.v_head_dim))
+    _, outs = jax.lax.scan(body, (), (qn, qr, jnp.arange(nq)))
+    return outs.transpose(1, 0, 2, 3, 4).reshape(B, S, H, v.shape[-1])
+
+
+def _attend_in_vmem(q_nope, q_rope, k_nope, k_rope, v, *, scale: float,
+                    interpret: bool):
+    """The causal core through the splash kernel: q = scale·[q_nope,
+    q_rope] (scaled in float32, rounded once), k = [k_nope, k_rope] with
+    the shared rope key on every head, per example (H,S,D). The kernel
+    keeps no score buffer in HBM, so nothing here is checkpointed: its
+    backward saves q, k, v, the output and the log-sum-exp."""
+    B, S, H, _ = q_nope.shape
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    q = (q.astype(jnp.float32) * scale).astype(q_nope.dtype)
+    k_rope = jnp.broadcast_to(k_rope[:, :, None, :],
+                              (B, S, H, k_rope.shape[-1]))
+    k = jnp.concatenate([k_nope, k_rope], axis=-1)
+
+    def core(q, k, v):
+        # splash caches the mask's block info by mask and block shape, so
+        # a second trace (the layer's remat) does not build it again
+        h, s = q.shape[1:3]
+        mask = splash.MultiHeadMask([splash.CausalMask((s, s))] * h)
+        kernel = splash.make_splash_mha(mask, block_sizes=BLOCKS,
+                                        head_shards=1, q_seq_shards=1,
+                                        interpret=interpret)
+        return jax.vmap(kernel)(q, k, v)
+
+    q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+    return hints.per_head_shard(core, q, k, v).transpose(0, 2, 1, 3)
 
 
 def init_mla_cache(cfg, batch: int, seq_len: int, dtype) -> MLACache:

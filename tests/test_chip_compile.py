@@ -1,11 +1,13 @@
 """Compile for one TPU v5e chip that is described, not attached: the
-Pallas kernels at real widths and phase B's peer local step of
+Pallas kernels at real widths, DeepSeek-V2-Lite's latent attention at
+the benchmark cell's shapes, and phase B's peer local step of
 ``chip_smoke.py``. Nothing runs; the TPU compiler refuses what the chip
 would refuse (unaligned tiles, too much fast memory, too much HBM).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file."""
+import functools
 import os
 import re
 import sys
@@ -278,3 +280,83 @@ def test_expert_groups_stay_on_their_shard(v5e_2x2):
         re.search(r"= \(s32\[([0-9,]+)\]", line)
         for line in text.splitlines() if " sort(" in line) if m]
     assert sorted_ints and set(sorted_ints) == {"1,256"}, sorted_ints
+
+
+def _kernel_scopes(text):
+    """The ``BLOCK_SCOPES`` scope of each Pallas kernel call in a
+    compiled program's text."""
+    sys.path.insert(0, os.path.join(ROOT, "bench"))
+    import moe_yardstick
+    import scopecut
+
+    names = scopecut.op_names(text)
+    return [moe_yardstick.block_scope(names.get(m.group(1)))
+            for m in (re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+) = .*"
+                               r'custom_call_target="tpu_custom_call"', line)
+                      for line in text.splitlines()) if m]
+
+
+@pytest.mark.parametrize("examples", [0, 2], ids=["plain", "vmapped"])
+def test_mla_attention_runs_in_vmem(one_chip, examples):
+    """One DeepSeek-V2-Lite latent-attention layer at the benchmark
+    cell's micro-batch (4 x 4096, 16 heads), forward and backward, and
+    the same under ``jax.vmap`` over a leading axis (the validator's
+    replay): the causal core is the splash kernel, forward in the
+    forward, and forward and the fused backward in the gradient, each
+    under scope ``mla``; no float32 score buffer of the query-blocked
+    path is left."""
+    from repro.configs.registry import get_config
+    from repro.models import mla
+
+    cfg = get_config("deepseek-v2-lite")
+    rows = (examples,) if examples else ()
+    x = _sds((*rows, 4, 4096, cfg.d_model), jnp.bfloat16, one_chip)
+    params = jax.tree.map(
+        lambda s: _sds(s.shape, s.dtype, one_chip),
+        jax.eval_shape(lambda: mla.init_mla(jax.random.PRNGKey(0), cfg)))
+
+    layer = functools.partial(mla.attend_full, cfg=cfg)
+    if examples:
+        layer = jax.vmap(layer, (None, 0))
+
+    def loss(p, x):
+        return jnp.sum(layer(p, x).astype(jnp.float32))
+
+    fwd = jax.jit(layer).lower(params, x).compile().as_text()
+    bwd = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile().as_text()
+    assert _kernel_scopes(fwd) == ["mla"]
+    assert _kernel_scopes(bwd) == ["mla"] * 2
+    for text in (fwd, bwd):
+        assert not re.search(r"f32\[(?:\d+,)*4,16,512,4096\]", text)
+
+
+@pytest.mark.parametrize("data,model", [(1, 1), (2, 2)])
+def test_mesh_step_runs_mla_in_vmem(v5e_2x2, data, model):
+    """DeepSeek-V2-Lite's heads at their published widths in
+    ``make_step``'s DeMo step at 1,024 tokens, the benchmark cell's
+    program, on a (data, model) mesh where 'data' splits the tokens
+    within one peer: inside the peer ``shard_map``, the layer scan and
+    the remat, each layer's attention core lowers to the splash kernel,
+    its examples over 'data' and its heads over 'model' (forward; and
+    forward again and the fused backward in the gradient), under scope
+    ``mla``."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.configs.base import InputShape, TrainConfig
+    from repro.configs.registry import reduced_config
+    from repro.launch.steps import make_step
+
+    mesh = Mesh(np.array(v5e_2x2[:data * model]).reshape(data, model),
+                ("data", "model"))
+    cfg = reduced_config("deepseek-v2-lite")
+    cfg = cfg.with_overrides(peer_axes=("pod",), mla=dataclasses.replace(
+        cfg.mla, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128))
+    plan = make_step(cfg, TrainConfig(demo_chunk=16, demo_topk=4), mesh,
+                     InputShape("t", seq_len=1024, global_batch=4,
+                                kind="train"),
+                     variant="demo", microbatch=2, scan_layers=True)
+    scopes = _kernel_scopes(plan.lower(mesh).compile().as_text())
+    assert scopes.count("mla") == 3 * cfg.num_layers, scopes
