@@ -25,6 +25,7 @@ from repro.econ import profits
 from repro.configs.registry import tiny_config
 from repro.core import byzantine
 from repro.data import pipeline
+from repro.schemes import apply_update
 from repro.schemes import demo as demo_opt
 from repro.schemes import demo as compress
 from repro.models import model as M
@@ -55,7 +56,7 @@ def run(peers: int = 5, batch: int = 8, seq_len: int = 64, seed: int = 0):
         b = pipeline.select_data(corpus, seed, f"p{i}", 0, batch, seq_len)
         g = grad(params, b)
         pl, _ = demo_opt.local_step(
-            g, demo_opt.init_state(params), beta=hp.demo_beta,
+            g, jax.tree.map(jnp.zeros_like, params), beta=hp.demo_beta,
             chunk=hp.demo_chunk, k=hp.demo_topk, metas=metas)
         payloads.append(pl)
     attacked = payloads[:-1] + [byzantine.norm_attack(payloads[-1])]
@@ -67,7 +68,7 @@ def run(peers: int = 5, batch: int = 8, seq_len: int = 64, seed: int = 0):
     def agg_loss(pls, normalize, apply_sign):
         delta = demo_opt.aggregate(pls, metas, normalize=normalize,
                                    apply_sign=apply_sign)
-        p2 = demo_opt.apply_update(params, delta, lr)
+        p2 = apply_update(params, delta, lr)
         return delta, float(loss_j(p2, eval_b)) - l0
 
     clean_ref, _ = agg_loss(payloads, True, True)

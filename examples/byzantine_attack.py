@@ -12,6 +12,7 @@ from repro.configs.base import TrainConfig
 from repro.configs.registry import tiny_config
 from repro.core import byzantine
 from repro.data import pipeline
+from repro.schemes import apply_update
 from repro.schemes import demo as demo_opt
 from repro.schemes import demo as compress
 from repro.models import model as M
@@ -29,8 +30,8 @@ def main():
     def run(defended: bool, rounds: int = 10):
         params = M.init_params(cfg, jax.random.PRNGKey(0))
         metas = compress.tree_meta(params, hp.demo_chunk)
-        states = {f"p{i}": demo_opt.init_state(params) for i in range(4)}
-        states["evil"] = demo_opt.init_state(params)
+        ef0 = jax.tree.map(jnp.zeros_like, params)
+        states = {uid: ef0 for uid in ("p0", "p1", "p2", "p3", "evil")}
         losses = [float(loss_j(params, eval_b))]
         for rnd in range(rounds):
             payloads = []
@@ -46,7 +47,7 @@ def main():
             delta = demo_opt.aggregate(payloads, metas,
                                        normalize=defended,
                                        apply_sign=defended)
-            params = demo_opt.apply_update(params, delta, lr)
+            params = apply_update(params, delta, lr)
             losses.append(float(loss_j(params, eval_b)))
         return losses
 

@@ -15,34 +15,15 @@ _HEAD_AXIS: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
     "repro_head_axis", default=None)
 
 
-_CHUNK_AXES: contextvars.ContextVar[Optional[tuple]] = contextvars.ContextVar(
-    "repro_chunk_axes", default=None)
-
-
 @contextlib.contextmanager
-def axis_hints(head: Optional[str] = None, chunk: Optional[tuple] = None):
-    toks = (_HEAD_AXIS.set(head), _CHUNK_AXES.set(chunk))
+def axis_hints():
+    """Within this context :func:`constrain_heads` shards attention heads
+    over the 'model' mesh axis."""
+    tok = _HEAD_AXIS.set("model")
     try:
         yield
     finally:
-        _HEAD_AXIS.reset(toks[0])
-        _CHUNK_AXES.reset(toks[1])
-
-
-def constrain_chunks(x):
-    """Hint for DeMo compression-domain tensors (num_chunks, ...): shard
-    the chunk-row dim over the tp axes. Without this, the flatten/pad
-    reshapes inside dct.encode defeat GSPMD propagation and XLA
-    REPLICATES every params-sized fp32 stage of the compression pipeline
-    (measured: ~12 full-tensor all-gathers per step on deepseek-v2)."""
-    axes = _CHUNK_AXES.get()
-    if not axes:
-        return x
-    try:
-        spec = P(tuple(axes), *([None] * (x.ndim - 1)))
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x
+        _HEAD_AXIS.reset(tok)
 
 
 def per_chunk_shard(fn, x):

@@ -37,8 +37,7 @@ SKIPS = {
 
 def run_one(arch: str, shape_name: str, *, multi_pod: bool, variant: str,
             out_dir: str, remat: bool = True, ce_chunks: int = 16,
-            agg_sharding: str = "param", donate: bool = True,
-            ef_dtype: str = None, tag: str = "", microbatch: int = 1,
+            donate: bool = True, tag: str = "", microbatch: int = 1,
             chunk_len: int = 0, intra_dtype: str = "",
             verbose: bool = True) -> dict:
     cfg = get_config(arch)
@@ -63,8 +62,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, variant: str,
     if shape.kind == "train":
         train_kw = {"remat": remat, "ce_chunks": ce_chunks,
                     "donate": donate, "microbatch": microbatch}
-        if variant == "demo":
-            train_kw.update(agg_sharding=agg_sharding, ef_dtype=ef_dtype)
     plan = make_step(cfg, hp, mesh, shape, variant=variant, **train_kw)
     lowered = plan.lower(mesh)
     t_lower = time.time() - t0
@@ -114,11 +111,7 @@ def main(argv=None):
     ap.add_argument("--ce-chunks", type=int, default=16,
                     help="chunked CE (production default; 0 = naive full "
                          "logits, the paper-faithful baseline)")
-    ap.add_argument("--agg-sharding", default="param",
-                    choices=["param", "replicated"])
     ap.add_argument("--no-donate", action="store_true")
-    ap.add_argument("--ef-dtype", default=None,
-                    help="error-feedback buffer dtype (default param_dtype)")
     ap.add_argument("--tag", default="",
                     help="suffix for the output JSON (perf iterations)")
     ap.add_argument("--microbatch", type=int, default=1,
@@ -139,9 +132,7 @@ def main(argv=None):
                               variant=args.variant, out_dir=args.out_dir,
                               remat=not args.no_remat,
                               ce_chunks=args.ce_chunks,
-                              agg_sharding=args.agg_sharding,
-                              donate=not args.no_donate,
-                              ef_dtype=args.ef_dtype, tag=args.tag,
+                              donate=not args.no_donate, tag=args.tag,
                               microbatch=args.microbatch,
                               chunk_len=args.chunk_len,
                               intra_dtype=args.intra_dtype)

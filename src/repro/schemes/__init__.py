@@ -9,12 +9,14 @@ scheme is behind :class:`GradScheme` —
 
 * the **payload** pytree type (whatever the scheme puts in a bucket),
   its wire size, and structural format validation;
-* peer-side production: per-peer optimizer state (error feedback) and
-  the fused ``local_step`` (grads → payload);
-* validator-side evaluation: ``single_peer_delta`` (the dense signed
-  update a LossScore evaluates) and the fused, jit-shareable
-  ``aggregate_apply`` (the coordinated model update every replica runs
-  bit-identically);
+* peer-side production: per-peer state (the error-feedback tree, shaped
+  like the parameters) and the fused ``local_step`` (grads → payload);
+* aggregation: ``aggregate`` (stacked payloads → the dense signed Δ),
+  which the launcher's mesh step runs after its ``all_gather``, and the
+  fused, jit-shareable ``aggregate_apply`` built on it (the coordinated
+  model update the validator and every peer replica run
+  bit-identically); ``single_peer_delta`` (the dense signed update a
+  LossScore evaluates);
 * host-level payload staging: ``stack/pad/take_payloads`` over the
   leading peer axis — generic pytree ops, so the static-shape padded
   round entry points work for any payload layout;
@@ -28,8 +30,9 @@ Schemes register by name (``@register_scheme``) and are selected via
 default; ``repro.schemes.randk`` (seeded random-k sparsification with
 sign-SGD aggregation) proves the pipeline is scheme-generic.
 
-Every method that runs inside jit (``local_step``, ``aggregate_apply``,
-``single_peer_delta``, ``flatten_for_sketch``, the payload tree ops)
+Every method that runs inside jit (``local_step``, ``aggregate``,
+``aggregate_apply``, ``single_peer_delta``, ``flatten_for_sketch``, the
+payload tree ops)
 must be traceable; everything else is host-side. Scheme instances hold
 only *derived shape metadata* (e.g. DCT chunk layouts), never parameter
 arrays — they ride inside shared jit-cache closures.
@@ -40,6 +43,8 @@ from typing import Any, Callable, Dict, List, Sequence, Tuple, Type
 
 import jax
 import jax.numpy as jnp
+
+from repro.obs import trace
 
 
 def tree_signature(params) -> tuple:
@@ -75,11 +80,12 @@ class GradScheme:
 
     # ------------------------------------------------- peer production
     def init_state(self, params):
-        """Fresh per-peer optimizer state (e.g. error feedback)."""
-        raise NotImplementedError
+        """Fresh per-peer state: the zero error-feedback tree, shaped and
+        typed like ``params``."""
+        return jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), params)
 
-    def local_step(self, grads, state, batch=None):
-        """(grads, state[, the consumed batch]) -> (payload, new state).
+    def local_step(self, grads, ef, batch=None):
+        """(grads, ef[, the consumed batch]) -> (payload, new ef).
 
         ``batch`` is the peer's primary (assigned) batch; schemes whose
         payload layout is data-derived (e.g. rand-k index selection
@@ -96,13 +102,21 @@ class GradScheme:
         the batched primary eval."""
         raise NotImplementedError
 
+    def aggregate(self, stacked, weights=None):
+        """Dense signed update Δ, shaped like the parameters, from
+        payloads stacked on a leading peer axis (``stack_payloads`` on
+        the host, ``jax.lax.all_gather`` on a mesh). ``weights`` (K,)
+        defaults to 1/K each; zero-weight rows must be exact ±0.0 no-ops
+        so padded calls stay bit-identical to unpadded ones."""
+        raise NotImplementedError
+
     def aggregate_apply(self, params, stacked, rows, lr, weights=None):
         """One fused coordinated-update step: gather ``rows`` (peer
         indices) from the stacked payloads, aggregate and apply
-        θ ← θ − α·Δ. ``weights`` (len(rows),) supports static-shape
-        padding: zero-weight rows must be exact ±0.0 no-ops so padded
-        calls stay bit-identical to unpadded ones."""
-        raise NotImplementedError
+        θ ← θ − α·Δ (no weight decay). ``weights`` (len(rows),) as in
+        :meth:`aggregate`: padded rows leave the result bit-identical."""
+        delta = self.aggregate(self.take_payloads(stacked, rows), weights)
+        return apply_update(params, delta, lr)
 
     def shared_aggregate_apply(self, params):
         """One jitted :meth:`aggregate_apply` per (cache_key, tree
@@ -195,6 +209,17 @@ class GradScheme:
         raise NotImplementedError
 
 
+def apply_update(params, delta, lr, weight_decay: float = 0.0):
+    """θ ← (1 − α·λ)·θ − α·Δ (decoupled wd, matches AdamW convention)."""
+    def upd(p, d):
+        p32 = p.astype(jnp.float32)
+        if weight_decay:
+            p32 = p32 * (1.0 - lr * weight_decay)
+        return (p32 - lr * d.astype(jnp.float32)).astype(p.dtype)
+    with jax.named_scope(trace.SCOPE_APPLY):
+        return jax.tree.map(upd, params, delta)
+
+
 # one compiled aggregate program per (scheme knobs, tree signature),
 # process-wide — validators and peers all fetch the same callable
 _AGG_JIT_CACHE: Dict[tuple, Any] = {}
@@ -229,5 +254,5 @@ from repro.schemes import randk as _randk    # noqa: E402,F401
 
 __all__ = [
     "GradScheme", "SCHEMES", "register_scheme", "get_scheme",
-    "make_scheme", "tree_signature",
+    "make_scheme", "tree_signature", "apply_update",
 ]

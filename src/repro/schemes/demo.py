@@ -12,9 +12,10 @@ A compressed pseudo-gradient ("payload") is, per parameter tensor:
 Payloads are dict pytrees mirroring the param tree, so they ride through
 jit/pjit/shard_map and ``jax.lax.all_gather`` unchanged.
 
-This module is the ONLY place that owns the DeMo payload layout: the
-validator, peers, audit and simulator reach it through the scheme object
-(``hp.scheme = "demo"``), and the DeMo-specific mesh step / codec tests
+This module is the ONLY place that owns the DeMo payload layout, on a
+mesh too (``DemoScheme.aggregate`` shards the gathered chunk rows): the
+launcher's mesh step, the validator, peers, audit and simulator reach it
+through the scheme object (``hp.scheme = "demo"``), and the codec tests
 import the functions below directly. The aggregation accepts payloads
 with a leading peer axis (as produced by ``jax.lax.all_gather`` over the
 peer mesh axes) or a list of payloads (the host-level validator path).
@@ -26,6 +27,7 @@ from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro import hints
 from repro.demo import dct
@@ -119,17 +121,6 @@ def pad_payloads(stacked, total: int):
         stacked, is_leaf=_is_payload)
 
 
-def take_payloads(stacked, rows):
-    """Select ``rows`` along the leading peer axis of a stacked payload
-    tree (traceable — the validator reuses its already-stacked eval-set
-    payloads for top-G aggregation by gathering rows inside jit)."""
-    rows = jnp.asarray(rows, jnp.int32)
-    return jax.tree.map(
-        lambda p: Payload(vals=jnp.take(p.vals, rows, axis=0),
-                          idx=jnp.take(p.idx, rows, axis=0)),
-        stacked, is_leaf=_is_payload)
-
-
 def tree_meta(params, s: int) -> Dict[str, Any]:
     return jax.tree.map(lambda x: dct.chunk_meta(x.shape, s), params)
 
@@ -191,44 +182,30 @@ def flatten_payloads_for_sketch(stacked) -> List[Tuple[Any, Any]]:
 # ------------------------------------------------------------- optimizer
 
 
-class DemoState(NamedTuple):
-    ef: object            # error-feedback buffer, pytree like params
-    step: jnp.ndarray
-
-
-def init_state(params, dtype=None) -> DemoState:
-    mk = (lambda x: jnp.zeros(x.shape, dtype or x.dtype))
-    return DemoState(ef=jax.tree.map(mk, params),
-                     step=jnp.zeros((), jnp.int32))
-
-
-def local_step(grads, state: DemoState, *, beta: float, chunk: int,
-               k: int, metas=None, encode_fn=None):
+def local_step(grads, ef, *, beta: float, chunk: int, k: int, metas=None,
+               encode_fn=None):
     """One peer's pseudo-gradient production.
 
-    Returns (payload_tree, new_state). ``encode_fn`` lets the caller swap in
-    the Pallas kernel pipeline; default is the jnp reference.
+    Returns (payload_tree, new_ef), the new error feedback in ``ef``'s
+    dtypes. ``encode_fn`` lets the caller swap in the Pallas kernel
+    pipeline; default is the jnp reference.
     """
     metas = metas or tree_meta(grads, chunk)
 
     def per_leaf(e, g, m):
         with jax.named_scope(trace.SCOPE_ENCODE):
-            e = beta * e.astype(jnp.float32) + g.astype(jnp.float32)
-            coeffs = (encode_fn or dct.encode)(e, m)
+            e32 = beta * e.astype(jnp.float32) + g.astype(jnp.float32)
+            coeffs = (encode_fn or dct.encode)(e32, m)
         payload = topk_compress(coeffs, k)
-        z = dct.decode(topk_decompress(payload, m.s * m.s), m)
         with jax.named_scope(trace.SCOPE_DECODE):
-            e_new = e - z
-        return payload, e_new
+            z = dct.decode(topk_decompress(payload, m.s * m.s), m)
+            return payload, (e32 - z).astype(e.dtype)
 
-    flat_e, treedef = jax.tree.flatten(state.ef)
-    flat_g = jax.tree.leaves(grads)
-    flat_m = jax.tree.leaves(metas)
-    outs = [per_leaf(e, g, m) for e, g, m in zip(flat_e, flat_g, flat_m)]
-    payloads = jax.tree.unflatten(treedef, [o[0] for o in outs])
-    new_ef = jax.tree.unflatten(
-        treedef, [o[1].astype(e.dtype) for o, e in zip(outs, flat_e)])
-    return payloads, DemoState(ef=new_ef, step=state.step + 1)
+    flat_e, treedef = jax.tree.flatten(ef)
+    outs = [per_leaf(e, g, m) for e, g, m in zip(
+        flat_e, jax.tree.leaves(grads), jax.tree.leaves(metas))]
+    return (jax.tree.unflatten(treedef, [o[0] for o in outs]),
+            jax.tree.unflatten(treedef, [o[1] for o in outs]))
 
 
 def aggregate(payloads, metas, weights: Optional[jnp.ndarray] = None,
@@ -267,46 +244,13 @@ def aggregate(payloads, metas, weights: Optional[jnp.ndarray] = None,
                                     p.idx.shape)
             grid = grid.at[rows, p.idx].add(
                 p.vals.astype(jnp.float32) * w[:, None, None])
-            grid = hints.constrain_chunks(grid)   # keep the dense fp32 grid
-        delta = dct.decode(grid, m)               # sharded (no-op on hosts)
+        delta = dct.decode(grid, m)
         if not apply_sign:
             return delta
         with jax.named_scope(trace.SCOPE_APPLY):
             return jnp.sign(delta)
 
     return jax.tree.map(combine, stacked, metas, is_leaf=_is_payload)
-
-
-def apply_update(params, delta, lr, weight_decay: float = 0.0):
-    """θ ← (1 − α·λ)·θ − α·Δ (decoupled wd, matches AdamW convention)."""
-    def upd(p, d):
-        p32 = p.astype(jnp.float32)
-        if weight_decay:
-            p32 = p32 * (1.0 - lr * weight_decay)
-        return (p32 - lr * d.astype(jnp.float32)).astype(p.dtype)
-    with jax.named_scope(trace.SCOPE_APPLY):
-        return jax.tree.map(upd, params, delta)
-
-
-def aggregate_apply(params, stacked, rows, lr, weights=None, *, metas,
-                    normalize: bool = True, apply_sign: bool = True):
-    """One fused coordinated-update step: gather ``rows`` (peer indices)
-    from the stacked payloads, aggregate (Algo 2) and apply θ ← θ − α·Δ.
-
-    Validator and peers both jit this exact function (with metas bound),
-    so every replica runs the same compiled program and stays bit-identical.
-    ``rows`` lets the validator reuse its already-stacked eval-set payloads
-    for top-G aggregation without re-fetching or re-stacking. ``weights``
-    (len(rows),) supports static-shape padding: callers pad ``rows`` to a
-    fixed bucket and zero the padded entries' weights, which multiply
-    every padded contribution down to exact ±0.0 adds — the aggregate is
-    bit-identical to the unpadded call. None keeps the uniform 1/K
-    default.
-    """
-    sub = take_payloads(stacked, rows)
-    delta = aggregate(sub, metas, weights=weights, normalize=normalize,
-                      apply_sign=apply_sign)
-    return apply_update(params, delta, lr)
 
 
 def single_peer_delta(payload_tree, metas, apply_sign: bool = True):
@@ -316,6 +260,23 @@ def single_peer_delta(payload_tree, metas, apply_sign: bool = True):
     if apply_sign:
         dense = jax.tree.map(jnp.sign, dense)
     return dense
+
+
+def _rows_over_free_axes(stacked):
+    """Shard the chunk rows (axis 1) of stacked payloads over the mesh
+    axes that are not yet manual, 'model' first: in the launcher's peer
+    step those are the tensor-parallel axes, so the decode runs on each
+    device's own rows and GSPMD moves data only where rows cross the
+    param sharding. With no such axis (no mesh, or every axis manual)
+    the payloads are left as they are."""
+    mesh = jax.sharding.get_abstract_mesh()
+    free = sorted((a for a in mesh.axis_names if a not in mesh.manual_axes),
+                  key=lambda a: (a != "model", a))
+    if not free:
+        return stacked
+    spec = P(None, tuple(free), None)
+    return jax.tree.map(
+        lambda x: jax.lax.with_sharding_constraint(x, spec), stacked)
 
 
 # ------------------------------------------------------------- scheme
@@ -336,11 +297,8 @@ class DemoScheme(GradScheme):
                 self.hp.demo_topk)
 
     # ------------------------------------------------- peer production
-    def init_state(self, params):
-        return init_state(params)
-
-    def local_step(self, grads, state, batch=None):
-        return local_step(grads, state, beta=self.hp.demo_beta,
+    def local_step(self, grads, ef, batch=None):
+        return local_step(grads, ef, beta=self.hp.demo_beta,
                           chunk=self.hp.demo_chunk, k=self.hp.demo_topk,
                           metas=self.metas)
 
@@ -348,9 +306,9 @@ class DemoScheme(GradScheme):
     def single_peer_delta(self, payload):
         return single_peer_delta(payload, self.metas)
 
-    def aggregate_apply(self, params, stacked, rows, lr, weights=None):
-        return aggregate_apply(params, stacked, rows, lr, weights,
-                               metas=self.metas)
+    def aggregate(self, stacked, weights=None):
+        return aggregate(_rows_over_free_axes(stacked), self.metas,
+                         weights=weights)
 
     # (payload staging: the generic GradScheme stack/pad/take ops apply
     # as-is — Payload is a NamedTuple pytree node, so they stack/pad/

@@ -82,11 +82,6 @@ def _select_idx(n: int, k: int, seed, leaf_salt: int) -> jnp.ndarray:
     return idx.astype(jnp.int32)
 
 
-class RandKState(NamedTuple):
-    ef: Any               # error-feedback buffer, pytree like params
-    step: jnp.ndarray
-
-
 @register_scheme
 class RandKScheme(GradScheme):
     """Seeded random-k + sign-SGD, bound to one param tree's leaf sizes."""
@@ -106,15 +101,10 @@ class RandKScheme(GradScheme):
                 self._ks)
 
     # ------------------------------------------------- peer production
-    def init_state(self, params):
-        return RandKState(
-            ef=jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), params),
-            step=jnp.zeros((), jnp.int32))
-
-    def local_step(self, grads, state, batch=None):
+    def local_step(self, grads, ef, batch=None):
         seed = (batch_seed(batch) if batch is not None
                 else jnp.uint32(self.hp.seed))
-        flat_e, treedef = jax.tree.flatten(state.ef)
+        flat_e, treedef = jax.tree.flatten(ef)
         flat_g = jax.tree.leaves(grads)
         payloads, new_ef = [], []
         for li, (e, g, k) in enumerate(zip(flat_e, flat_g, self._ks)):
@@ -128,8 +118,7 @@ class RandKScheme(GradScheme):
             payloads.append(RandKPayload(vals=vals, idx=idx))
             new_ef.append(e_new.astype(e.dtype))
         return (jax.tree.unflatten(treedef, payloads),
-                RandKState(ef=jax.tree.unflatten(treedef, new_ef),
-                           step=state.step + 1))
+                jax.tree.unflatten(treedef, new_ef))
 
     # -------------------------------------------- validator evaluation
     def single_peer_delta(self, payload):
@@ -142,25 +131,21 @@ class RandKScheme(GradScheme):
             out.append(jnp.sign(flat).reshape(shape))
         return jax.tree.unflatten(self._treedef(), out)
 
-    def aggregate_apply(self, params, stacked, rows, lr, weights=None):
-        sub = self.take_payloads(stacked, rows)
-        K = jax.tree.leaves(sub)[0].shape[0]
+    def aggregate(self, stacked, weights=None):
+        K = jax.tree.leaves(stacked)[0].shape[0]
         if weights is None:
             weights = jnp.full((K,), 1.0 / K, jnp.float32)
         # per-peer global L2 over the kept entries (norm-attack defense)
         sq = sum(jnp.sum(p.vals.astype(jnp.float32) ** 2, axis=-1)
-                 for p in jax.tree.leaves(sub, is_leaf=_is_rk))
+                 for p in jax.tree.leaves(stacked, is_leaf=_is_rk))
         w = (weights * (1.0 / (jnp.sqrt(sq) + 1e-12))).astype(jnp.float32)
-
-        def combine(p: RandKPayload, param):
-            n = param.size
+        out = []
+        for p, n, shape in zip(jax.tree.leaves(stacked, is_leaf=_is_rk),
+                               self._leaf_sizes(), self._leaf_shapes()):
             flat = jnp.zeros((n,), jnp.float32).at[p.idx.reshape(-1)].add(
                 (p.vals.astype(jnp.float32) * w[:, None]).reshape(-1))
-            delta = jnp.sign(flat).reshape(param.shape)
-            p32 = param.astype(jnp.float32) - lr * delta
-            return p32.astype(param.dtype)
-
-        return jax.tree.map(combine, sub, params, is_leaf=_is_rk)
+            out.append(jnp.sign(flat).reshape(shape))
+        return jax.tree.unflatten(self._treedef(), out)
 
     # ------------------------------------------------------ wire format
     def payload_bytes(self, payload) -> int:

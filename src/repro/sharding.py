@@ -399,7 +399,3 @@ def named(mesh, spec_tree):
     return jax.tree.map(
         lambda s: NamedSharding(mesh, s), spec_tree,
         is_leaf=lambda x: isinstance(x, P))
-
-
-# re-export the trace-time hints (separate module to avoid import cycles)
-from repro.hints import axis_hints, constrain_heads  # noqa: E402,F401

@@ -8,9 +8,14 @@ except ImportError:
     from _hypothesis_compat import given, settings, st
 
 from repro.demo import dct
+from repro.schemes import apply_update
 from repro.schemes import demo as compress
 from repro.schemes import demo as optimizer
 from repro.schemes.demo import Payload
+
+
+def _zeros(params):
+    return jax.tree.map(jnp.zeros_like, params)
 
 
 def _setup(key=0, shape=(64, 48), chunk=16):
@@ -24,31 +29,28 @@ def _setup(key=0, shape=(64, 48), chunk=16):
 def test_error_feedback_conservation():
     """After one step from zero EF: e_new = g - decode(payload)."""
     params, grads, metas = _setup()
-    st_ = optimizer.init_state(params)
-    payloads, st2 = optimizer.local_step(grads, st_, beta=0.9, chunk=16,
-                                         k=8, metas=metas)
+    payloads, ef = optimizer.local_step(grads, _zeros(params), beta=0.9,
+                                        chunk=16, k=8, metas=metas)
     z = compress.decompress_tree(payloads, metas)
-    np.testing.assert_allclose(np.asarray(st2.ef["w"]),
+    np.testing.assert_allclose(np.asarray(ef["w"]),
                                np.asarray(grads["w"] - z["w"]), atol=1e-5)
 
 
 def test_ef_accumulates_with_beta():
     params, grads, metas = _setup()
-    st_ = optimizer.init_state(params)
-    st_ = st_._replace(ef={"w": jnp.ones_like(params["w"])})
-    payloads, st2 = optimizer.local_step(grads, st_, beta=0.5, chunk=16,
-                                         k=8, metas=metas)
+    ef = {"w": jnp.ones_like(params["w"])}
+    payloads, ef = optimizer.local_step(grads, ef, beta=0.5, chunk=16,
+                                        k=8, metas=metas)
     z = compress.decompress_tree(payloads, metas)
     expect = 0.5 * 1.0 + grads["w"] - z["w"]
-    np.testing.assert_allclose(np.asarray(st2.ef["w"]), np.asarray(expect),
+    np.testing.assert_allclose(np.asarray(ef["w"]), np.asarray(expect),
                                atol=1e-5)
 
 
 def test_aggregate_is_signed():
     params, grads, metas = _setup()
-    st_ = optimizer.init_state(params)
-    p1, _ = optimizer.local_step(grads, st_, beta=0.9, chunk=16, k=8,
-                                 metas=metas)
+    p1, _ = optimizer.local_step(grads, _zeros(params), beta=0.9,
+                                 chunk=16, k=8, metas=metas)
     delta = optimizer.aggregate([p1, p1], metas)
     vals = np.unique(np.asarray(delta["w"]))
     assert set(vals).issubset({-1.0, 0.0, 1.0})
@@ -58,9 +60,8 @@ def test_normalization_neutralizes_rescaling():
     """Byzantine defense (§4): scaling one peer's payload by 1e6 changes
     nothing after DCT-domain normalization."""
     params, grads, metas = _setup()
-    st_ = optimizer.init_state(params)
-    p1, _ = optimizer.local_step(grads, st_, beta=0.9, chunk=16, k=8,
-                                 metas=metas)
+    p1, _ = optimizer.local_step(grads, _zeros(params), beta=0.9,
+                                 chunk=16, k=8, metas=metas)
     p_scaled = jax.tree.map(
         lambda p: Payload(vals=p.vals * 1e6, idx=p.idx), p1,
         is_leaf=lambda x: isinstance(x, Payload))
@@ -71,9 +72,8 @@ def test_normalization_neutralizes_rescaling():
 
 def test_without_normalization_rescaling_dominates():
     params, grads, metas = _setup()
-    st_ = optimizer.init_state(params)
-    p1, _ = optimizer.local_step(grads, st_, beta=0.9, chunk=16, k=8,
-                                 metas=metas)
+    p1, _ = optimizer.local_step(grads, _zeros(params), beta=0.9,
+                                 chunk=16, k=8, metas=metas)
     p_neg = jax.tree.map(lambda p: Payload(vals=-1e6 * p.vals, idx=p.idx),
                          p1, is_leaf=lambda x: isinstance(x, Payload))
     d = optimizer.aggregate([p1, p_neg], metas, normalize=False)
@@ -86,7 +86,7 @@ def test_without_normalization_rescaling_dominates():
 def test_apply_update_moves_by_lr():
     params = {"w": jnp.zeros((8, 8))}
     delta = {"w": jnp.ones((8, 8))}
-    out = optimizer.apply_update(params, delta, lr=0.1)
+    out = apply_update(params, delta, lr=0.1)
     np.testing.assert_allclose(np.asarray(out["w"]), -0.1, atol=1e-7)
 
 
@@ -95,12 +95,11 @@ def test_apply_update_moves_by_lr():
 def test_compression_residual_shrinks_with_k(k, beta):
     """Larger k ⇒ decode(payload) closer to the EF buffer."""
     params, grads, metas = _setup(key=k)
-    st_ = optimizer.init_state(params)
-    p_small, _ = optimizer.local_step(grads, st_, beta=beta, chunk=16,
-                                      k=k, metas=metas)
-    st_ = optimizer.init_state(params)
-    p_big, _ = optimizer.local_step(grads, st_, beta=beta, chunk=16,
-                                    k=min(16 * 16, k * 2), metas=metas)
+    p_small, _ = optimizer.local_step(grads, _zeros(params), beta=beta,
+                                      chunk=16, k=k, metas=metas)
+    p_big, _ = optimizer.local_step(grads, _zeros(params), beta=beta,
+                                    chunk=16, k=min(16 * 16, k * 2),
+                                    metas=metas)
     z_s = compress.decompress_tree(p_small, metas)["w"]
     z_b = compress.decompress_tree(p_big, metas)["w"]
     r_s = float(jnp.sum((grads["w"] - z_s) ** 2))

@@ -211,14 +211,13 @@ def test_kernel_backed_local_step_equals_ref():
     params = {"w": jax.random.normal(jax.random.PRNGKey(3), (64, 48))}
     grads = {"w": jax.random.normal(jax.random.PRNGKey(4), (64, 48))}
     metas = compress.tree_meta(params, 16)
-    st1 = optimizer.init_state(params)
-    p_ref, s_ref = optimizer.local_step(grads, st1, beta=0.9, chunk=16, k=8,
-                                        metas=metas)
-    st2 = optimizer.init_state(params)
-    p_k, s_k = optimizer.local_step(grads, st2, beta=0.9, chunk=16, k=8,
-                                    metas=metas,
-                                    encode_fn=functools.partial(
-                                        ops.demo_encode, interpret=True))
+    ef = jax.tree.map(jnp.zeros_like, params)
+    p_ref, _ = optimizer.local_step(grads, ef, beta=0.9, chunk=16, k=8,
+                                    metas=metas)
+    p_k, _ = optimizer.local_step(grads, ef, beta=0.9, chunk=16, k=8,
+                                  metas=metas,
+                                  encode_fn=functools.partial(
+                                      ops.demo_encode, interpret=True))
     np.testing.assert_allclose(np.asarray(p_ref["w"].vals),
                                np.asarray(p_k["w"].vals), atol=1e-4)
     np.testing.assert_array_equal(np.asarray(p_ref["w"].idx),

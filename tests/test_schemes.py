@@ -179,9 +179,9 @@ def test_randk_error_feedback_removes_shipped_coordinates():
     scheme = RandKScheme(_hp("randk", randk_frac=0.08), params)
     grads = {"w": jnp.linspace(1.0, 2.0, 100).reshape(10, 10)}
     batch = {"tokens": jnp.ones((2, 8), jnp.int32)}
-    payload, state = scheme.local_step(grads, scheme.init_state(params),
-                                       batch=batch)
-    ef = np.asarray(state.ef["w"]).reshape(-1)
+    payload, ef = scheme.local_step(grads, scheme.init_state(params),
+                                    batch=batch)
+    ef = np.asarray(ef["w"]).reshape(-1)
     idx = np.asarray(payload["w"].idx)
     # shipped coordinates left the buffer; the rest accumulated
     np.testing.assert_allclose(ef[idx], 0.0, atol=1e-7)

@@ -13,8 +13,7 @@ from repro.configs.registry import tiny_config
 from repro.core import padding
 from repro.core.gauntlet import Validator
 from repro.schemes import demo as compress
-from repro.schemes import demo as demo_opt
-from repro.schemes.demo import Payload
+from repro.schemes.demo import DemoScheme, Payload
 from repro.training.peer import PeerConfig
 from repro.training.round_loop import build_sim
 
@@ -228,20 +227,19 @@ def test_padded_aggregate_rows_are_exact_noops():
     to the unpadded call (the bit-identity contract validator and peer
     replicas rely on)."""
     params = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
-    metas = compress.tree_meta(params, 4)
+    scheme = DemoScheme(TrainConfig(demo_chunk=4, demo_topk=3), params)
+    metas = scheme.metas
     payloads = [compress.compress_tree(
         jax.tree.map(lambda x: jnp.cos(x + i), params), metas, 3)
         for i in range(2)]
     stacked = compress.stack_payloads(payloads)
-    base = demo_opt.aggregate_apply(
-        params, stacked, jnp.arange(2, dtype=jnp.int32),
-        jnp.float32(0.1), metas=metas)
+    base = scheme.aggregate_apply(
+        params, stacked, jnp.arange(2, dtype=jnp.int32), jnp.float32(0.1))
     padded = compress.pad_payloads(stacked, 8)
     weights = jnp.asarray([0.5, 0.5] + [0.0] * 6, jnp.float32)
     rows = jnp.asarray([0, 1] + [0] * 6, jnp.int32)
-    out = demo_opt.aggregate_apply(params, padded, rows,
-                                   jnp.float32(0.1), weights,
-                                   metas=metas)
+    out = scheme.aggregate_apply(params, padded, rows, jnp.float32(0.1),
+                                 weights)
     for lb, lo in zip(jax.tree.leaves(base), jax.tree.leaves(out)):
         np.testing.assert_array_equal(np.asarray(lb), np.asarray(lo))
 

@@ -15,11 +15,12 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
+    import dataclasses
     import json
     import jax, jax.numpy as jnp
     from repro.configs.registry import tiny_config
     from repro.configs.base import TrainConfig, InputShape
-    from repro.launch.steps import make_demo_train_step, make_ddp_train_step
+    from repro.launch.steps import make_ddp_train_step, make_train_step
     from repro.launch import analysis
     from repro.launch.mesh import make_host_mesh
 
@@ -32,8 +33,8 @@ SCRIPT = textwrap.dedent("""
 
     # donate=False: this test re-reads `params` after the call (donation
     # is the production default but deletes the input buffers)
-    plan = make_demo_train_step(cfg, hp, mesh, shape, remat=False,
-                                donate=False)
+    plan = make_train_step(cfg, hp, mesh, shape, remat=False,
+                           donate=False)
     compiled = plan.lower(mesh).compile()
 
     from repro.models.model import init_params
@@ -73,13 +74,66 @@ SCRIPT = textwrap.dedent("""
     mesh_dp = make_host_mesh(data=16)
     shape_dp = InputShape("t", seq_len=128, global_batch=16, kind="train")
     cbd = analysis.collective_bytes(
-        make_demo_train_step(cfg, hp, mesh_dp, shape_dp, remat=False)
+        make_train_step(cfg, hp, mesh_dp, shape_dp, remat=False)
         .lower(mesh_dp).compile().as_text())
     cbdd = analysis.collective_bytes(
         make_ddp_train_step(cfg, hp, mesh_dp, shape_dp, remat=False)
         .lower(mesh_dp).compile().as_text())
     out["dp_demo_collectives"] = {k: v for k, v in cbd.items()}
     out["dp_ddp_collectives"] = {k: v for k, v in cbdd.items()}
+
+    # one codec: on a 4-peer mesh, with and without a model axis, each
+    # peer's new error feedback is the scheme's local_step on that peer's
+    # gradient, and the new params are apply_update of the scheme's
+    # aggregate of the stacked payloads
+    from repro.demo.schedules import warmup_cosine
+    from repro.models.model import loss_fn
+    from repro.schemes import apply_update, make_scheme
+    grad = jax.jit(jax.grad(lambda p, b: loss_fn(p, b, cfg)[0]))
+    out["parity"] = {}
+    for (name, mesh_name), mesh_p in (
+            ((n, m), make_host_mesh(data=4, model=int(m[-1])))
+            for n in ("demo", "randk") for m in ("4x1", "4x4")):
+        hp_s = dataclasses.replace(hp, scheme=name, randk_frac=0.05)
+        plan = make_train_step(cfg, hp_s, mesh_p, shape, remat=False,
+                               donate=False)
+        ef0 = jax.tree.map(
+            lambda s: 0.01 * jax.random.normal(key, s.shape, s.dtype),
+            plan.args[1])
+        with jax.set_mesh(mesh_p):
+            p_mesh, ef_mesh, _ = plan.lower(mesh_p).compile()(
+                params, ef0, batch, jnp.int32(10))
+        scheme = make_scheme(hp_s, params)
+        payloads, ef_ref = [], []
+        for k in range(K):
+            b = jax.tree.map(lambda x: x[2 * k:2 * k + 2], batch)
+            pl, e = scheme.local_step(
+                grad(params, b), jax.tree.map(lambda x: x[k], ef0), batch=b)
+            payloads.append(pl)
+            ef_ref.append(e)
+        lr = warmup_cosine(jnp.int32(10), base_lr=hp_s.learning_rate,
+                           warmup_steps=hp_s.warmup_steps,
+                           total_steps=hp_s.total_steps)
+        stacked = scheme.stack_payloads(payloads)
+        p_ref = apply_update(params, scheme.aggregate(stacked), lr,
+                             weight_decay=hp_s.weight_decay)
+        # the payload layout on a mesh is the scheme's own business
+        with jax.set_mesh(mesh_p):
+            jaxpr = jax.make_jaxpr(scheme.aggregate)(stacked)
+        row_specs = sorted({str(e.params["sharding"].spec)
+                            for e in jaxpr.eqns
+                            if e.primitive.name == "sharding_constraint"})
+        ef_err = max(
+            float(jnp.max(jnp.abs(a[k] - b)))
+            for k in range(K) for a, b in zip(jax.tree.leaves(ef_mesh),
+                                               jax.tree.leaves(ef_ref[k])))
+        p_err = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(
+            jax.tree.leaves(p_mesh), jax.tree.leaves(p_ref)))
+        moved = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(
+            jax.tree.leaves(p_mesh), jax.tree.leaves(params)))
+        out["parity"][f"{name}@{mesh_name}"] = {
+            "ef_err": ef_err, "p_err": p_err, "moved": moved,
+            "row_specs": row_specs}
     print("RESULT::" + json.dumps(out))
 """)
 
@@ -127,3 +181,30 @@ def test_demo_collective_bytes_beat_ddp(result):
     ddp_total = sum(v for k, v in result["dp_ddp_collectives"].items()
                     if k != "count")
     assert demo_total < ddp_total, (demo_total, ddp_total)
+
+
+@pytest.mark.parametrize(
+    "scheme,mesh", [("demo", "4x1"), ("randk", "4x1"), ("demo", "4x4"),
+                    ("randk", "4x4")],
+    ids=["demo", "randk", "demo-4x4", "randk-4x4"])
+def test_mesh_step_runs_the_schemes_codec(result, scheme, mesh):
+    """One codec: ``make_train_step`` on a 4-peer mesh, with a model axis
+    of 1 or 4 devices, gives each peer the error feedback
+    ``scheme.local_step`` gives on that peer's gradient, and params equal
+    to ``apply_update`` of ``scheme.aggregate`` over the four payloads,
+    weight decay included."""
+    r = result["parity"][f"{scheme}@{mesh}"]
+    assert r["moved"] > 0
+    assert r["ef_err"] < 1e-5, r
+    assert r["p_err"] < 1e-6, r
+
+
+@pytest.mark.parametrize("scheme,rows", [
+    ("demo", ["PartitionSpec(None, ('model', 'data'), None)"]),
+    ("randk", [])], ids=["demo", "randk"])
+def test_payload_layout_belongs_to_the_scheme(result, scheme, rows):
+    """Under a mesh, DeMo's ``aggregate`` shards the gathered chunk rows
+    over the free mesh axes, 'model' first; rand-k's payload rows are
+    random flat indices, so its ``aggregate`` adds no constraint."""
+    for mesh in ("4x1", "4x4"):
+        assert result["parity"][f"{scheme}@{mesh}"]["row_specs"] == rows
